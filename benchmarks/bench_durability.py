@@ -221,4 +221,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.runtime import init_compile_cache
+    init_compile_cache()
     main()

@@ -15,8 +15,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-INF = jnp.float32(3.4e38)
+INF = np.float32(3.4e38)
 
 
 def edge_sources(indptr: jnp.ndarray, m_cap: int) -> jnp.ndarray:

@@ -509,12 +509,11 @@ class ShardedStore:
         """The live sharded state pytree, allocated lazily: AOT-lowering
         consumers (``state_struct``/``*_program``) never pay for it."""
         if self._live_state is None:
+            # built sharded over the mesh axis: each device allocates only
+            # its own shards, never the whole stack on one device
             self._live_state = ge.make_sharded_state(
-                self.sspec, self.pspec, self.n_shards, self.n_per_shard)
-            # the broadcast-built fresh state can share one device buffer
-            # across zero-filled leaves — XLA refuses to donate an aliased
-            # buffer twice, so the first dispatch must not donate
-            self._pinned = self._live_state
+                self.sspec, self.pspec, self.n_shards, self.n_per_shard,
+                self.mesh, self.axis)
         return self._live_state
 
     @state.setter
@@ -750,9 +749,7 @@ class ShardedStore:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
         # the leading dim of every leaf is the shard dim: place it over
-        # the mesh axis explicitly (a FRESH state's broadcast-built leaves
-        # sit on one device until the first dispatch, so copying the
-        # template's sharding would strand the restore there)
+        # the mesh axis explicitly
         sharding = NamedSharding(self.mesh, P(self.axis))
         self._live_state = jax.tree.map(
             lambda a: jax.device_put(jnp.asarray(a), sharding), state)

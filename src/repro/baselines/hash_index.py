@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.core.keys import pack_keys
 
-EMPTY = jnp.uint32(0xFFFFFFFF)
+EMPTY = np.uint32(0xFFFFFFFF)
 
 
 class HashState(NamedTuple):

@@ -13,6 +13,9 @@ TPU adaptation: per-vertex ``malloc``'d edge arrays become contiguous block
   **defragmentation** — a fully-vectorized rebuild (sort + cumsum re-layout)
   that doubles as the allocator's garbage collector. Bump allocation between
   defrags replaces free lists (TPUs want bulk re-layout, not pointer reuse).
+  Rows at or past ``next_block`` are always empty (dst -1, weight 0, ts 0):
+  fresh pools and rebuild images start empty and every write lands below
+  ``next_block``, so newly allocated extents need no initializing writes.
 * every entry carries a timestamp; reads at ``read_ts`` give MVCC snapshot
   semantics (paper §3.3 "Version management" — old functional states are the
   versioned arrays).
@@ -25,6 +28,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .vertex_table import VertexTable
 from repro.kernels import ops as kops
@@ -32,7 +36,7 @@ from repro.kernels import ops as kops
 __all__ = ["EdgePool", "PoolSpec", "make_edge_pool", "apply_edge_updates",
            "get_neighbors", "live_edges", "defrag"]
 
-INT_MAX = jnp.int32(0x7FFFFFFF)
+INT_MAX = np.int32(0x7FFFFFFF)
 
 
 @dataclass(frozen=True)
@@ -172,16 +176,30 @@ def _gather_vertex_entries(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
     return d, w, t, size
 
 
-def _scatter_entries(pool: EdgePool, tgt_block, lane, valid, d, w, t,
-                     owner_of_block=None):
+def _scatter_entries(pool: EdgePool, tgt_block, lane, valid, d, w, t):
+    """Land every valid op's (d, w, t) at its (block, lane) slot.
+
+    Slots are distinct but several ops can share a block row, so the ops
+    are merged per row in a (B, bs) staging buffer and each touched row is
+    written back whole, once. A row scatter updates the pool in place on a
+    TPU; an element scatter into the (nb, bs) pool relays the whole pool
+    out and back on every batch."""
     nb = pool.dst.shape[0]
     tb = jnp.where(valid, tgt_block, nb)
-    pool = pool._replace(
-        dst=pool.dst.at[tb, lane].set(d, mode="drop"),
-        weight=pool.weight.at[tb, lane].set(w, mode="drop"),
-        ts=pool.ts.at[tb, lane].set(t, mode="drop"),
-    )
-    return pool
+    order = jnp.argsort(tb)
+    stb = tb[order]
+    head = jnp.concatenate([jnp.ones((1,), bool), stb[1:] != stb[:-1]])
+    row = jnp.cumsum(head.astype(jnp.int32)) - 1     # staging row per op
+    row_blk = jnp.full(tb.shape, nb, jnp.int32).at[row].set(stb)
+    src = jnp.clip(row_blk, 0, nb - 1)
+    sl = lane[order]
+
+    def land(col, val):
+        staged = col[src].at[row, sl].set(val[order])
+        return col.at[row_blk].set(staged, mode="drop")
+
+    return pool._replace(dst=land(pool.dst, d), weight=land(pool.weight, w),
+                         ts=land(pool.ts, t))
 
 
 # --------------------------------------------------------------------------
@@ -193,10 +211,11 @@ def _alloc_extents(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
                    kincoming: jnp.ndarray):
     """Assign fresh extents to vertices with NO edge array yet (the mass
     first-touch case of every ingest stream). There is nothing to gather or
-    dedup — the whole batch's allocations are laid out with one cumsum and
-    initialized by a flat block-row scatter whose budget is proportional to
-    the BATCH (Σ blocks ≤ B/bs + Σ base_log), so thousands of new vertices
-    per batch never spill into the compaction tiers or force a defrag."""
+    dedup — the whole batch's allocations are laid out with one cumsum, and
+    their rows (past ``next_block``) are already empty. The block budget is
+    proportional to the BATCH (Σ blocks ≤ B/bs + Σ base_log), so thousands
+    of new vertices per batch never spill into the compaction tiers or
+    force a defrag."""
     bs = spec.block_size
     K = ku.shape[0]
     nb = pool.dst.shape[0]
@@ -220,10 +239,6 @@ def _alloc_extents(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
     krc = jnp.clip(krow, 0, K - 1)
     valid_r = (r < total) & fits
     tgt_rows = jnp.where(valid_r, pool.next_block + r, nb)
-    pool = _scatter_block_rows(pool, tgt_rows,
-                               jnp.full((R_total, bs), -1, jnp.int32),
-                               jnp.zeros((R_total, bs), jnp.float32),
-                               jnp.zeros((R_total, bs), jnp.int32))
     owner = pool.owner.at[tgt_rows].set(jnp.where(valid_r, ku[krc], -1),
                                         mode="drop")
 
@@ -335,12 +350,13 @@ def _compact_vertices(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
         fold_bitmap = jnp.zeros((K, Ww), jnp.uint32)
         fold_ku = jnp.full((K,), -1, jnp.int32)
 
-    # ---- write the new extents as whole BLOCK ROWS (extents are block-
-    # aligned, so a row scatter replaces bs entry scatters): content rows
-    # carry the compacted prefix padded with empties, then pure-empty log
-    # rows out to the extent end. ``MB`` bounds any extent this call can
-    # build: snapB <= R1 rows, logB <= max(blocks(dmax), buf_blocks) rows
-    # (the caller defrags instead when a vertex's incoming exceeds dmax).
+    # ---- write the new extents' content as whole BLOCK ROWS (extents are
+    # block-aligned, so a row scatter replaces bs entry scatters): the
+    # compacted prefix padded with empties. The log rows out to the extent
+    # end lie past ``next_block`` and are already empty. ``MB`` bounds any
+    # extent this call can build: snapB <= R1 rows, logB <= max(blocks(dmax),
+    # buf_blocks) rows (the caller defrags instead when a vertex's incoming
+    # exceeds dmax).
     R1 = _cdiv(width, bs)
     padw = R1 * bs - width
     if padw:
@@ -358,14 +374,6 @@ def _compact_vertices(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
         jnp.where(fillm, ct, 0).reshape(K * R1, bs))
 
     MB = R1 + max(_cdiv(spec.dmax, bs), spec.buf_blocks) + 1
-    T2 = MB - R1
-    rowi2 = jnp.arange(R1, MB, dtype=jnp.int32)[None, :]
-    row_ok2 = kmask[:, None] & (rowi2 < new_blocks[:, None])
-    pool = _scatter_block_rows(
-        pool, jnp.where(row_ok2, base[:, None] + rowi2, nb).reshape(-1),
-        jnp.full((K * T2, bs), -1, jnp.int32),
-        jnp.zeros((K * T2, bs), jnp.float32),
-        jnp.zeros((K * T2, bs), jnp.int32))
     cap_entries = new_blocks * bs
 
     # ownership: new extents -> u ; old extents -> -1 (garbage)
@@ -628,7 +636,7 @@ def _defrag_stream(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
                                dead_dst[jnp.clip(d0, 0, n_cap - 1)],
                                -1, d0)
                 cd, cw, ct, cnt, liv = kops.defrag_rows(
-                    dd, w0, t0, ksz, keep_all=keep_all, n_cap=n_cap,
+                    dd, w0, t0, ksz, keep_all=keep_all,
                     impl=spec.compact_impl)
                 cnt = jnp.where(kmask, cnt, 0)
                 d_cnt = d_cnt.at[jnp.where(kmask, ku, n_cap)].set(
@@ -906,26 +914,39 @@ def apply_edge_updates(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
     tiles_list = jnp.where(jnp.arange(n_tiles, dtype=jnp.int32) < n_touched,
                            t_order, t_pad)
 
-    use_pallas = spec.append_impl == "pallas" or (
-        spec.append_impl == "auto" and kops.default_impl() == "pallas")
-    if use_pallas:
+    if kops.resolve("append", spec.append_impl) == "pallas":
         # fused append: slot scatter + full-extent last-writer probe in one
         # VMEM-resident pass per TOUCHED pool tile — exact liveness, never
         # blind, and never a full-pool scan
         nd, nw, nt, win_was_live = kops.append_edges(
             pool.dst, pool.weight, pool.ts, tgt_blk, slot % bs, op_ok,
             sv, sw_, sts, p_start, p_sz, p_v, tiles=tiles_list,
-            n_touched=n_touched)
+            n_touched=n_touched, impl="pallas")
         pool = pool._replace(dst=nd, weight=nw, ts=nt)
         probe_blind = jnp.zeros((), bool)
     else:
         Wp = min(spec.probe_width, spec.dmax)
-        d_e, w_e, t_e, _ = _gather_vertex_entries(spec, pool, vt,
-                                                  probe_u, Wp)
-        t_match = jnp.where(d_e == v2[:, None], t_e, 0)  # clock starts at 1
-        newest = jnp.argmax(t_match, axis=1)
-        win_was_live = (jnp.max(t_match, axis=1) > 0) & \
-            (w_e[jnp.arange(B), newest] != 0)
+
+        def probe(width):
+            d_e, w_e, t_e, _ = _gather_vertex_entries(spec, pool, vt,
+                                                      probe_u, width)
+            t_match = jnp.where(d_e == v2[:, None], t_e, 0)  # clock from 1
+            newest = jnp.argmax(t_match, axis=1)
+            return (jnp.max(t_match, axis=1) > 0) & \
+                (w_e[jnp.arange(B), newest] != 0)
+
+        # the gather costs B x width: probe at the narrowest of Wp, Wp/2,
+        # ... (down to two blocks) that covers every probed extent of this
+        # batch — entries past an extent's size are masked, so any covering
+        # width gives the same answer
+        widths = [Wp]
+        while widths[0] > 2 * bs:
+            widths.insert(0, widths[0] // 2)
+        need = jnp.max(jnp.where(probe_u >= 0, jnp.minimum(p_sz, Wp), 0))
+        pick = jnp.sum((jnp.asarray(widths[:-1], jnp.int32) < need)
+                       .astype(jnp.int32))
+        win_was_live = jax.lax.switch(
+            pick, [functools.partial(probe, w) for w in widths])
         probe_blind = jnp.any((probe_u >= 0) & (p_sz > Wp))
         pool = _scatter_entries(pool, tgt_blk, slot % bs, op_ok, sv, sw_, sts)
 

@@ -208,6 +208,16 @@ def step_snapshot(sspec: SortSpec, pspec: ep.PoolSpec, m_cap: int,
 _snapshot = jax.jit(step_snapshot, static_argnums=(0, 1, 2))
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _fresh_state(sspec: SortSpec, pspec: ep.PoolSpec, n_max: int):
+    """An empty ``GraphState`` built by one program: every leaf is its own
+    device buffer (eager construction reuses one zero scalar for several
+    leaves, which buffer donation refuses)."""
+    return GraphState(sort=sort_mod.make_sort(sspec),
+                      vt=vt_mod.make_vertex_table(n_max),
+                      pool=ep.make_edge_pool(pspec))
+
+
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def _defrag(sspec: SortSpec, pspec: ep.PoolSpec, state: GraphState,
             incoming=None):
@@ -264,11 +274,7 @@ class RadixGraph:
                                      defrag_impl=self.defrag_impl,
                                      policy=self.policy,
                                      buf_blocks=self.buf_blocks)
-        self.state = GraphState(
-            sort=sort_mod.make_sort(self.sort_spec),
-            vt=vt_mod.make_vertex_table(self.n_max),
-            pool=ep.make_edge_pool(self.pool_spec),
-        )
+        self.state = _fresh_state(self.sort_spec, self.pool_spec, self.n_max)
         # retained MVCC versions: (label, version_ts, state)
         self._versions: list[tuple[int, int, GraphState]] = []
         self.dropped_ops: int = 0  # masked edge ops refused at capacity
@@ -293,11 +299,11 @@ class RadixGraph:
         self._seen_defrags: int = 0
         # pipelined-apply accounting: a flush is one ``_apply_edge_batches``
         # call (= one host sync point), a super-batch one device dispatch of
-        # up to ``pipeline_depth`` fused batches. The freshly-built state is
-        # pinned (donation-exempt): its zero-filled leaves can share one
-        # device buffer, which XLA refuses to donate twice — jitted outputs
-        # thereafter are distinct buffers and donate freely.
-        self._pinned: Optional[GraphState] = self.state
+        # up to ``pipeline_depth`` fused batches. ``_pinned`` names a state
+        # that must not be donated (a captured epoch or MVCC version); the
+        # fresh state is a jitted output with distinct leaf buffers, so the
+        # very first dispatch already donates.
+        self._pinned: Optional[GraphState] = None
         self.pipe_flushes: int = 0
         self.pipe_super_batches: int = 0
         self.pipe_stage_ms: float = 0.0   # host staging + async dispatch

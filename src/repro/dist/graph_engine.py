@@ -33,7 +33,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import analytics as alg
@@ -64,17 +64,25 @@ def shard_of_keys(keys: jnp.ndarray, n_shards: int) -> jnp.ndarray:
 
 
 def make_sharded_state(sspec: SortSpec, pspec: ep.PoolSpec, n_shards: int,
-                       n_per_shard: int) -> GraphState:
+                       n_per_shard: int, mesh=None,
+                       axis: Optional[str] = None) -> GraphState:
     """Fresh per-shard (SortState, VertexTable, EdgePool) pytrees stacked on
     a leading shard dim — the input/output carried by the engine's jitted
-    step functions (shard dim maps onto the mesh axis)."""
-    one = GraphState(
-        sort=sort_mod.make_sort(sspec),
-        vt=vt_mod.make_vertex_table(n_per_shard),
-        pool=ep.make_edge_pool(pspec),
-    )
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n_shards,) + x.shape), one)
+    step functions (shard dim maps onto the mesh axis). Given ``mesh`` and
+    ``axis``, the state is built by one program whose outputs are sharded
+    over that axis, so each device materializes only its own shards."""
+    def build():
+        one = GraphState(
+            sort=sort_mod.make_sort(sspec),
+            vt=vt_mod.make_vertex_table(n_per_shard),
+            pool=ep.make_edge_pool(pspec),
+        )
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n_shards,) + x.shape), one)
+
+    if mesh is None:
+        return build()
+    return jax.jit(build, out_shardings=NamedSharding(mesh, P(axis)))()
 
 
 def _bucket_slots(owner: jnp.ndarray, valid: jnp.ndarray, cap: int):
@@ -206,10 +214,10 @@ def make_apply_edges(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
         g, dropped = apply_one(g, sk, dk, w, mask)
         return jax.tree.map(lambda x: x[None], g), dropped[None]
 
-    sharded = shard_map(body, mesh=mesh,
-                        in_specs=(P(axis), P(axis), P(axis), P(axis),
-                                  P(axis)),
-                        out_specs=(P(axis), P(axis)), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=(P(axis), P(axis), P(axis), P(axis),
+                                      P(axis)),
+                            out_specs=(P(axis), P(axis)), check_vma=False)
 
     def apply_edges(state, src_keys, dst_keys, w, mask):
         B = src_keys.shape[0]
@@ -310,10 +318,10 @@ def make_apply_edges_pipelined(sspec: SortSpec, pspec: ep.PoolSpec, mesh,
         return (jax.tree.map(lambda x: x[None], g),
                 jnp.sum(drops, dtype=jnp.int32)[None])
 
-    sharded = shard_map(body, mesh=mesh,
-                        in_specs=(P(axis), P(None, axis), P(None, axis),
-                                  P(None, axis), P(None, axis)),
-                        out_specs=(P(axis), P(axis)), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh,
+                            in_specs=(P(axis), P(None, axis), P(None, axis),
+                                      P(None, axis), P(None, axis)),
+                            out_specs=(P(axis), P(axis)), check_vma=False)
 
     def apply_edges_pipelined(state, src_keys, dst_keys, w, mask):
         K, B = src_keys.shape[0], src_keys.shape[1]
@@ -448,8 +456,8 @@ def make_khop_counts(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
         return counts[my * Ql + idx]         # psum-replicated: no return hop
 
     body = body_degree if (k == 1 and m_cap is None) else body_khop
-    sharded = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                        out_specs=P(axis), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                            out_specs=P(axis), check_vma=False)
 
     def khop(state, query_keys):
         Q = query_keys.shape[0]
@@ -528,8 +536,8 @@ def make_sync_vertices(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
         return jax.tree.map(lambda x: x[None], g2)
 
     in_specs = (P(axis),) + ((P(axis),) if incremental else ())
-    sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=P(axis), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(axis), check_vma=False)
     return sharded
 
 
@@ -545,8 +553,8 @@ def make_snapshot(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
         snap = rg.step_snapshot(sspec, pspec, m_cap, g, read_ts)
         return jax.tree.map(lambda x: x[None], snap)
 
-    return shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                     out_specs=P(axis), check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P(axis), check_vma=False)
 
 
 def make_bfs(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
@@ -623,8 +631,8 @@ def make_bfs(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
             cond, lvl, (depth0, frontier0, jnp.int32(0), go0))
         return depth[None]
 
-    sharded = shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
-                        out_specs=P(axis), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                            out_specs=P(axis), check_vma=False)
     return sharded
 
 
@@ -787,8 +795,8 @@ def make_pagerank(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
 
     in_specs = (P(axis),) + ((P(axis),) if warm else ())
     out_specs = P(axis) if tol is None else (P(axis), P(axis))
-    sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return sharded
 
 
@@ -863,8 +871,8 @@ def make_wcc(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
 
     in_specs = (P(axis),) + ((P(axis),) if warm else ())
     out_specs = (P(axis), P(axis)) if warm else P(axis)
-    sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return sharded
 
 
@@ -934,8 +942,8 @@ def make_sssp(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
 
     in_specs = (P(axis), P()) + ((P(axis),) if warm else ())
     out_specs = (P(axis), P(axis)) if warm else P(axis)
-    sharded = shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
     return sharded
 
 
@@ -998,8 +1006,8 @@ def make_bfs_warm(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
         out = jnp.where(rowlive & (d <= max_iters), d, -1)[None]
         return out, it[None]
 
-    sharded = shard_map(body, mesh=mesh, in_specs=(P(axis), P(), P(axis)),
-                        out_specs=(P(axis), P(axis)), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(), P(axis)),
+                            out_specs=(P(axis), P(axis)), check_vma=False)
     return sharded
 
 
@@ -1018,8 +1026,8 @@ def make_degree_map(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
         deg = snap.indptr[1:] - snap.indptr[:-1]
         return jnp.where(mine, deg, 0)[None]
 
-    sharded = shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                        out_specs=P(axis), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
+                            out_specs=P(axis), check_vma=False)
     return sharded
 
 
@@ -1032,8 +1040,8 @@ def make_num_edges(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
         snap = rg.step_snapshot(sspec, pspec, m_cap, g, None)
         return snap.m.astype(jnp.int32)[None]
 
-    sharded = shard_map(body, mesh=mesh, in_specs=(P(axis),),
-                        out_specs=P(axis), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(axis),),
+                            out_specs=P(axis), check_vma=False)
     return sharded
 
 
@@ -1129,8 +1137,8 @@ def make_bc(sspec: SortSpec, pspec: ep.PoolSpec, mesh, axis: str,
                                   frontier_budget, impl)
         return jnp.where(mine, vals, 0.0)[None]
 
-    sharded = shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
-                        out_specs=P(axis), check_rep=False)
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                            out_specs=P(axis), check_vma=False)
     return sharded
 
 

@@ -14,22 +14,24 @@ XLA path runs separately:
 1. **probe** — for every distinct (owner, dst) pair of the batch, scan the
    owner's extent rows that fall inside this tile for the pair's newest
    entry (last-writer-wins by timestamp), accumulating (best_ts, best_w) in
-   VMEM scratch across tiles. Because appends only claim slots at/after the
+   SMEM across tiles. Because appends only claim slots at/after the
    owner's pre-batch size, probing bounded by ``psize`` commutes with the
    writes of the same tile;
 2. **slot scatter** — land every op's (dst, weight, ts) at its claimed slot
    (block, lane) when the slot falls inside the tile — the batched analogue
    of the paper's ``fetch_add`` log append, one pass for all three payloads
    instead of three XLA scatters;
-3. **liveness finalize** — after the last grid step, emit ``was_live`` per
-   pair ((best_ts > 0) & (best_w != 0)), the exact pre-batch pair liveness
-   that drives the O(1) ``live_m`` counter with NO bounded-window blind spot.
+3. **liveness** — the per-pair (best_ts, best_w) accumulators leave the
+   kernel, and ``was_live = (best_ts > 0) & (best_w != 0)`` is the exact
+   pre-batch pair liveness that drives the O(1) ``live_m`` counter with NO
+   bounded-window blind spot.
 
 The pool payloads alias their outputs (``input_output_aliases``), so tiles
 the batch never touches keep their contents without ever moving through
-VMEM. TPU grids are sequential, so the scratch accumulators and the
-revisited ``was_live`` output window are legal (same pattern as
-kernels/frontier.py). Validated in interpret mode (CPU container) against
+VMEM. TPU grids are sequential, so accumulating into the whole-array SMEM
+outputs across grid steps is legal. The op vectors live in SMEM because the
+loops read them one scalar at a time; pool rows are read and updated as
+(1, BS) vectors. Validated in interpret mode against
 ``ref.append_ref``, which itself matches the ``_scatter_entries`` +
 dense-probe semantics under the probe/write commutation invariant above.
 """
@@ -41,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import ops
 
 __all__ = ["append_pallas", "append_tile_rows"]
 
@@ -55,25 +59,30 @@ def append_tile_rows(nb: int, tile: int = 128) -> int:
     return tile
 
 
-def _kernel(tiles, n_touched, dp, wp, tp, wblk, wlane, wval, wd, ww, wts,
-            pstart, psize, pv, od, ow, ot, owas, best_ts, best_w):
+def _append_kernel(tiles, n_touched, dp, wp, tp, wblk, wlane, wval, wd, ww,
+                   wts, pstart, psize, pv, od, ow, ot, best_ts, best_w):
     T, BS = dp.shape
     B = wblk.shape[0]
     pid = pl.program_id(0)
     t0 = tiles[pid] * T
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
 
     @pl.when(pid == 0)
     def _():
-        best_ts[...] = jnp.zeros_like(best_ts)
-        best_w[...] = jnp.zeros_like(best_w)
-        owas[...] = jnp.zeros_like(owas)
+        def zero(q, _):
+            best_ts[q] = jnp.int32(0)
+            best_w[q] = jnp.float32(0)
+            return 0
+
+        jax.lax.fori_loop(0, B, zero, 0)
 
     # pid 0 always visits (an identity copy of its tile when the batch
     # touches nothing): the output VMEM windows must be initialized before
     # the pipeline flushes them over the aliased pool buffer
     @pl.when((pid < n_touched[0]) | (pid == 0))
     def _visit():
-        # ---- probe pass (pre-append tile contents) ----
+        # ---- probe pass (pre-append tile contents): one block row at a
+        # time as a (1, BS) vector, its newest match by masked reductions
         def probe(q, _):
             sb = pstart[q]
             sz = psize[q]
@@ -81,33 +90,33 @@ def _kernel(tiles, n_touched, dp, wp, tp, wblk, wlane, wval, wd, ww, wts,
             nblk = (sz + BS - 1) // BS
             lo = jnp.maximum(sb, t0)
             hi = jnp.minimum(sb + nblk, t0 + T)
-            ok_q = (sb >= 0) & (v >= 0)
 
             def row(r, _):
                 local = r - t0
+                d = dp[pl.ds(local, 1), :]
+                t = tp[pl.ds(local, 1), :]
+                hit = ((r - sb) * BS + lane < sz) & (d == v)
+                tm = jnp.max(jnp.where(hit, t, 0))
 
-                def lane(j, _):
-                    pos = (r - sb) * BS + j
-                    d = dp[local, j]
-                    t = tp[local, j]
-                    hit = ok_q & (pos < sz) & (d == v) & (t > best_ts[q])
+                @pl.when(tm > best_ts[q])
+                def _():
+                    best_ts[q] = tm
+                    best_w[q] = jnp.sum(jnp.where(hit & (t == tm),
+                                                  wp[pl.ds(local, 1), :],
+                                                  0.0))
 
-                    @pl.when(hit)
-                    def _():
-                        best_ts[q] = t
-                        best_w[q] = wp[local, j]
-
-                    return 0
-
-                jax.lax.fori_loop(0, BS, lane, 0)
                 return 0
 
-            jax.lax.fori_loop(lo, jnp.maximum(lo, hi), row, 0)
+            @pl.when((sb >= 0) & (v >= 0))
+            def _():
+                jax.lax.fori_loop(lo, jnp.maximum(lo, hi), row, 0)
+
             return 0
 
         jax.lax.fori_loop(0, B, probe, 0)
 
-        # ---- append pass: copy tile, land this tile's slots ----
+        # ---- append pass: copy tile, land this tile's slots (a row
+        # read-modify-write: Mosaic stores vectors, not scalars, to VMEM)
         od[...] = dp[...]
         ow[...] = wp[...]
         ot[...] = tp[...]
@@ -117,20 +126,15 @@ def _kernel(tiles, n_touched, dp, wp, tp, wblk, wlane, wval, wd, ww, wts,
 
             @pl.when((wval[j] != 0) & (blk >= t0) & (blk < t0 + T))
             def _():
-                b = blk - t0
-                ln = wlane[j]
-                od[pl.ds(b, 1), pl.ds(ln, 1)] = wd[j][None, None]
-                ow[pl.ds(b, 1), pl.ds(ln, 1)] = ww[j][None, None]
-                ot[pl.ds(b, 1), pl.ds(ln, 1)] = wts[j][None, None]
+                b = pl.ds(blk - t0, 1)
+                sel = lane == wlane[j]
+                od[b, :] = jnp.where(sel, wd[j], od[b, :])
+                ow[b, :] = jnp.where(sel, ww[j], ow[b, :])
+                ot[b, :] = jnp.where(sel, wts[j], ot[b, :])
 
             return 0
 
         jax.lax.fori_loop(0, B, wr, 0)
-
-    @pl.when(pid == pl.num_programs(0) - 1)
-    def _():
-        owas[...] = ((best_ts[...] > 0) &
-                     (best_w[...] != 0)).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -142,32 +146,33 @@ def append_pallas(dst, w, ts, wblk, wlane, wval, wd, ww, wts,
     last touched tile repeated out to the grid length; ``n_touched`` is its
     valid prefix. Omitting both falls back to visiting every tile."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = ops.interpret()
     NB, BS = dst.shape
     tile = append_tile_rows(NB, tile)
-    B = wblk.shape[0]
     n_tiles = NB // tile
     if tiles is None:
         tiles = jnp.arange(n_tiles, dtype=jnp.int32)
         n_touched = jnp.asarray(n_tiles, jnp.int32)
     ptile = pl.BlockSpec((tile, BS), lambda i, tl, nt: (tl[i], 0))
-    ops = pl.BlockSpec((B,), lambda i, tl, nt: (0,))
+    # per-op vectors and the probe accumulators are whole-array SMEM
+    # residents: the kernel reads and updates them one scalar at a time
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_tiles,),
-        in_specs=[ptile, ptile, ptile] + [ops] * 9,
-        out_specs=[ptile, ptile, ptile, ops],
-        scratch_shapes=[pltpu.VMEM((B,), jnp.int32),
-                        pltpu.VMEM((B,), jnp.float32)],
+        in_specs=[ptile, ptile, ptile] + [smem] * 9,
+        out_specs=[ptile, ptile, ptile, smem, smem],
     )
-    out = pl.pallas_call(
-        _kernel,
+    B = wblk.shape[0]
+    nd, nw, nt, best_ts, best_w = pl.pallas_call(
+        _append_kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((NB, BS), dst.dtype),
             jax.ShapeDtypeStruct((NB, BS), w.dtype),
             jax.ShapeDtypeStruct((NB, BS), ts.dtype),
             jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.float32),
         ],
         # pool payloads alias their outputs: untouched tiles keep their
         # contents without a copy (operand indices count the two
@@ -175,7 +180,6 @@ def append_pallas(dst, w, ts, wblk, wlane, wval, wd, ww, wts,
         input_output_aliases={2: 0, 3: 1, 4: 2},
         interpret=interpret,
     )(tiles, jnp.reshape(jnp.asarray(n_touched, jnp.int32), (1,)),
-      dst, w, ts, wblk, wlane, wval.astype(jnp.int32), wd, ww, wts,
-      pstart, psize, pv)
-    nd, nw, nt, was = out
-    return nd, nw, nt, was == 1
+      dst, w, ts, wblk, wlane, wval.astype(jnp.int32), wd,
+      ww.astype(jnp.float32), wts, pstart, psize, pv)
+    return nd, nw, nt, (best_ts > 0) & (best_w != 0)

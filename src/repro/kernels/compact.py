@@ -1,18 +1,25 @@
-"""Pallas TPU kernel: log compaction (paper Algorithm 2).
+"""Pallas TPU kernels: log compaction (paper Algorithm 2) and the defrag
+row compactor.
 
-One grid step compacts one vertex's edge array. The duplicate checker is a
-**VMEM-resident bitmap** (the paper's segmented bitmap maps 1:1 onto VMEM
-words); the reverse scan is a data-dependent sequential loop — exactly the
-pattern XLA cannot express but Pallas can, and on TPU it runs from VMEM at
-register speed while the next tile streams in.
+One grid step owns a block of ``_ROWS`` edge arrays (the sublane tile) at
+full row width D, resident in VMEM. The paper's reverse scan with a
+duplicate-checker bitmap becomes a reverse scan over positions that works
+on all rows of the block at once:
 
-Per the paper, the bitmap is *unmarked* by re-scanning the processed entries
-(O(d), not O(n)) so scratch persists cleanly across grid steps.
+* position j's (dst, weight, ts) are read as one (rows, 1) column by a
+  masked lane reduction — Mosaic has no scalar loads from VMEM, and a
+  vector select-and-reduce is its idiom for a dynamic column;
+* the duplicate checker is a (rows, D) ``seen`` mask instead of a bitmap
+  over the vertex universe: when position j is scanned, every earlier
+  position holding the same destination is marked in one vector compare,
+  so its cost is O(D / 128) vector ops per scanned position and it needs
+  no VMEM proportional to the vertex count;
+* survivors land at their emission rank with a vector select over the
+  row (no scalar stores to VMEM).
 
-TPU target notes: D (edge-array tile width) should be a multiple of 128
-lanes; the bitmap covers the vertex-offset universe (n_cap bits -> n_cap/8
-bytes of VMEM; 1M vertices = 128 KiB, far under the 16 MiB budget). Validated
-here in interpret mode (CPU container) against ``ref.compact_rows_ref``.
+The scan runs only up to the block's largest occupied size, so empty and
+short rows cost little. Validated in interpret mode against
+``ref.compact_rows_ref`` / ``ref.defrag_rows_ref``.
 """
 from __future__ import annotations
 
@@ -23,251 +30,158 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ops
+
 __all__ = ["compact_rows_pallas", "defrag_rows_pallas"]
 
+_ROWS = 8   # edge arrays per grid step: one sublane tile
 
-def _kernel(dst_ref, w_ref, ts_ref, size_ref, odst_ref, ow_ref, ots_ref,
-            ocnt_ref, bitmap):
-    D = dst_ref.shape[1]
 
-    # zero the duplicate checker once; thereafter the unmark pass restores it
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        bitmap[...] = jnp.zeros_like(bitmap)
+def _col(ref, j):
+    """Column ``j`` of a (rows, D) VMEM block as a (rows, 1) vector. Rows
+    wider than one lane tile read only the 128-lane chunk holding j."""
+    D = ref.shape[1]
+    if D > 128 and D % 128 == 0:
+        base = pl.multiple_of((j // 128) * 128, 128)
+        x = ref[:, pl.ds(base, 128)]
+        j = j - base
+    else:
+        x = ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.sum(jnp.where(lane == j, x, jnp.zeros_like(x)), axis=1,
+                   keepdims=True)
 
-    # outputs must be fully initialized (empty slots = -1 / 0 / 0)
-    odst_ref[...] = jnp.full_like(odst_ref, -1)
-    ow_ref[...] = jnp.zeros_like(ow_ref)
-    ots_ref[...] = jnp.zeros_like(ots_ref)
 
-    size = size_ref[0, 0]
+def _dedup_scan(size_ref, dst_ref, w_ref, ts_ref, seen, on_survivor):
+    """Reverse scan shared by both kernels: for every position j (newest
+    first) decide whether it is its destination's newest occupied entry
+    with a non-NULL weight, call ``on_survivor(j, keep, d, w, t, cnt)``,
+    and mark older entries of the same destination as seen. Returns the
+    per-row survivor count (rows, 1)."""
+    R, D = dst_ref.shape
+    size = size_ref[...]
+    seen[...] = jnp.zeros_like(seen)
+    n = jnp.minimum(jnp.max(size), D)
 
-    def scan(i, cnt):
-        j = size - 1 - i                      # reverse scan (most recent first)
-        d = dst_ref[0, j]
-        word = jnp.right_shift(d, 5)
-        bit = jnp.uint32(1) << (d & 31).astype(jnp.uint32)
-        seen = (bitmap[word] & bit) != 0
-        live = (d >= 0) & ~seen
-        emit = live & (w_ref[0, j] != 0)
+    def step(i, cnt):
+        j = n - 1 - i
+        d = _col(dst_ref, j)
+        w = _col(w_ref, j)
+        t = _col(ts_ref, j)
+        valid = (j < size) & (d >= 0)
+        keep = valid & (_col(seen, j) == 0) & (w != 0)
+        on_survivor(j, keep, d, w, t, cnt)
+        seen[...] = jnp.where(valid & (dst_ref[...] == d), 1, seen[...])
+        return cnt + keep.astype(jnp.int32)
 
-        @pl.when(emit)
-        def _():
-            odst_ref[0, pl.ds(cnt, 1)] = d[None]
-            ow_ref[0, pl.ds(cnt, 1)] = w_ref[0, j][None]
-            ots_ref[0, pl.ds(cnt, 1)] = ts_ref[0, j][None]
+    return jax.lax.fori_loop(0, n, step, jnp.zeros((R, 1), jnp.int32))
 
-        @pl.when(d >= 0)
-        def _():
-            bitmap[word] = bitmap[word] | bit  # mark visited (even tombstones)
 
-        return cnt + jnp.where(emit, 1, 0)
+def _place(odst, ow, ots, at, d, w, t):
+    """Write (d, w, t) into lane ``at`` of each row where ``at`` matches."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, odst.shape, 1)
+    put = lane == at
+    odst[...] = jnp.where(put, d, odst[...])
+    ow[...] = jnp.where(put, w, ow[...])
+    ots[...] = jnp.where(put, t, ots[...])
 
-    cnt = jax.lax.fori_loop(0, size, scan, jnp.int32(0))
-    ocnt_ref[0, 0] = cnt
 
-    # unmark pass (paper Alg. 2 lines 9–11): restore bitmap to all-zero
-    def unmark(i, _):
-        d = dst_ref[0, i]
+def _clear(odst, ow, ots):
+    odst[...] = jnp.full(odst.shape, -1, odst.dtype)
+    ow[...] = jnp.zeros(ow.shape, ow.dtype)
+    ots[...] = jnp.zeros(ots.shape, ots.dtype)
 
-        @pl.when(d >= 0)
-        def _():
-            word = jnp.right_shift(d, 5)
-            bit = jnp.uint32(1) << (d & 31).astype(jnp.uint32)
-            bitmap[word] = bitmap[word] & ~bit
 
+def _compact_kernel(size_ref, dst_ref, w_ref, ts_ref, odst, ow, ots, ocnt,
+                    seen):
+    """Survivors in reverse-scan order (newest first), front-packed."""
+    _clear(odst, ow, ots)
+
+    def emit(j, keep, d, w, t, cnt):
+        _place(odst, ow, ots, jnp.where(keep, cnt, -1), d, w, t)
+
+    ocnt[...] = _dedup_scan(size_ref, dst_ref, w_ref, ts_ref, seen, emit)
+
+
+def _defrag_kernel(size_ref, dst_ref, w_ref, ts_ref, odst, ow, ots, ocnt,
+                   seen, live):
+    """Survivors sorted by destination ASCENDING (the defrag's CSR
+    discipline): the reverse scan marks each row's survivors in ``live``,
+    then a second pass places survivor j at its destination's rank among
+    the row's live destinations."""
+    R, D = dst_ref.shape
+    _clear(odst, ow, ots)
+    live[...] = jnp.zeros_like(live)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, D), 1)
+
+    def mark(j, keep, d, w, t, cnt):
+        live[...] = jnp.where(lane == j, keep.astype(jnp.int32), live[...])
+
+    cnt = _dedup_scan(size_ref, dst_ref, w_ref, ts_ref, seen, mark)
+    ocnt[...] = cnt
+    n = jnp.minimum(jnp.max(size_ref[...]), D)
+
+    def place(j, _):
+        d = _col(dst_ref, j)
+        keep = _col(live, j) != 0
+        rank = jnp.sum(jnp.where((live[...] != 0) & (dst_ref[...] < d), 1, 0),
+                       axis=1, keepdims=True)
+        _place(odst, ow, ots, jnp.where(keep, rank, -1), d,
+               _col(w_ref, j), _col(ts_ref, j))
         return 0
 
-    jax.lax.fori_loop(0, size, unmark, 0)
+    jax.lax.fori_loop(0, n, place, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("n_cap", "interpret"))
-def compact_rows_pallas(dst, w, ts, size, read_ts=None, *,
-                        n_cap: int | None = None, interpret: bool | None = None):
-    """Drop-in for ``ref.compact_rows_ref`` (same outputs, same order)."""
+def _rows_call(kernel, n_scratch, dst, w, ts, size, interpret):
+    """Run a row kernel over (K, D) edge arrays in blocks of ``_ROWS``
+    rows (K padded with empty rows; weights ride as float32)."""
     K, D = dst.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if read_ts is not None:  # MVCC filter applied before the scan
-        ok = ts <= jnp.asarray(read_ts, ts.dtype)
-        dst = jnp.where(ok, dst, -1)
-    if n_cap is None:
-        n_cap = 1 << 20  # default bitmap universe (128 KiB VMEM)
-    words = (n_cap + 31) // 32
-
-    grid = (K,)
-    row = lambda i: (i, 0)
-    out = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, 1), row),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, 1), row),
-        ],
+    Kp = -(-K // _ROWS) * _ROWS
+    pad = ((0, Kp - K), (0, 0))
+    wdtype = w.dtype
+    args = (jnp.pad(size.astype(jnp.int32), (0, Kp - K)).reshape(Kp, 1),
+            jnp.pad(dst, pad, constant_values=-1),
+            jnp.pad(w.astype(jnp.float32), pad),
+            jnp.pad(ts, pad))
+    row = pl.BlockSpec((_ROWS, D), lambda i: (i, 0))
+    col = pl.BlockSpec((_ROWS, 1), lambda i: (i, 0))
+    odst, ow, ots, ocnt = pl.pallas_call(
+        kernel,
+        grid=(Kp // _ROWS,),
+        in_specs=[col, row, row, row],
+        out_specs=[row, row, row, col],
         out_shape=[
-            jax.ShapeDtypeStruct((K, D), dst.dtype),
-            jax.ShapeDtypeStruct((K, D), w.dtype),
-            jax.ShapeDtypeStruct((K, D), ts.dtype),
-            jax.ShapeDtypeStruct((K, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Kp, D), dst.dtype),
+            jax.ShapeDtypeStruct((Kp, D), jnp.float32),
+            jax.ShapeDtypeStruct((Kp, D), ts.dtype),
+            jax.ShapeDtypeStruct((Kp, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((words,), jnp.uint32)],
+        scratch_shapes=[pltpu.VMEM((_ROWS, D), jnp.int32)] * n_scratch,
         interpret=interpret,
-    )(dst, w, ts, size.reshape(K, 1).astype(jnp.int32))
-    odst, ow, ots, ocnt = out
-    return odst, ow, ots, ocnt[:, 0]
+    )(*args)
+    return odst[:K], ow[:K].astype(wdtype), ots[:K], ocnt[:K, 0]
 
 
-# --------------------------------------------------------------------------
-# defrag row compactor: the streaming rebuild's per-vertex pass
-# --------------------------------------------------------------------------
-
-def _defrag_kernel(dst_ref, w_ref, ts_ref, size_ref, odst_ref, ow_ref,
-                   ots_ref, ocnt_ref, seen, seen2, live, prefix):
-    """Like the log compactor above, but survivors are emitted sorted by
-    destination ASCENDING (the defrag's CSR discipline) instead of
-    reverse-scan order. Three passes over the row's O(d) occupied entries
-    plus one O(n_cap/32) sweep over the bitmap words:
-
-    1. reverse scan marks the duplicate checker (``seen``) and, for each
-       destination's newest non-tombstone entry, the ``live`` bitmap;
-    2. a prefix-popcount over ``live`` words turns the bitmap into the
-       survivors' emission ranks, and a second reverse scan (deduped via
-       ``seen2``) places each winner at
-       ``prefix[word] + popcount(live_word & (bit - 1))`` — its
-       destination's rank among all live destinations;
-    3. the unmark pass (paper Alg. 2 lines 9-11) restores all three
-       bitmaps to zero so scratch persists cleanly across grid steps.
-    """
-    W = live.shape[0]
-    size = size_ref[0, 0]
-
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        seen[...] = jnp.zeros_like(seen)
-        seen2[...] = jnp.zeros_like(seen2)
-        live[...] = jnp.zeros_like(live)
-
-    odst_ref[...] = jnp.full_like(odst_ref, -1)
-    ow_ref[...] = jnp.zeros_like(ow_ref)
-    ots_ref[...] = jnp.zeros_like(ots_ref)
-
-    def scan(i, cnt):
-        j = size - 1 - i                      # reverse: most recent first
-        d = dst_ref[0, j]
-        word = jnp.right_shift(d, 5)
-        bit = jnp.uint32(1) << (d & 31).astype(jnp.uint32)
-        first = (d >= 0) & ((seen[word] & bit) == 0)
-        emit = first & (w_ref[0, j] != 0)
-
-        @pl.when(emit)
-        def _():
-            live[word] = live[word] | bit
-
-        @pl.when(d >= 0)
-        def _():
-            seen[word] = seen[word] | bit
-
-        return cnt + jnp.where(emit, 1, 0)
-
-    cnt = jax.lax.fori_loop(0, size, scan, jnp.int32(0))
-    ocnt_ref[0, 0] = cnt
-
-    def pre(wi, acc):
-        prefix[wi] = acc
-        return acc + jax.lax.population_count(live[wi]).astype(jnp.int32)
-
-    jax.lax.fori_loop(0, W, pre, jnp.int32(0))
-
-    def place(i, _):
-        j = size - 1 - i
-        d = dst_ref[0, j]
-        word = jnp.right_shift(d, 5)
-        bit = jnp.uint32(1) << (d & 31).astype(jnp.uint32)
-        winner = (d >= 0) & ((seen2[word] & bit) == 0) & \
-            ((live[word] & bit) != 0)
-
-        @pl.when(winner)
-        def _():
-            rank = prefix[word] + jax.lax.population_count(
-                live[word] & (bit - 1)).astype(jnp.int32)
-            odst_ref[0, pl.ds(rank, 1)] = d[None]
-            ow_ref[0, pl.ds(rank, 1)] = w_ref[0, j][None]
-            ots_ref[0, pl.ds(rank, 1)] = ts_ref[0, j][None]
-
-        @pl.when(d >= 0)
-        def _():
-            seen2[word] = seen2[word] | bit
-
-        return 0
-
-    jax.lax.fori_loop(0, size, place, 0)
-
-    def unmark(i, _):
-        d = dst_ref[0, i]
-
-        @pl.when(d >= 0)
-        def _():
-            word = jnp.right_shift(d, 5)
-            bit = jnp.uint32(1) << (d & 31).astype(jnp.uint32)
-            seen[word] = seen[word] & ~bit
-            seen2[word] = seen2[word] & ~bit
-            live[word] = live[word] & ~bit
-
-        return 0
-
-    jax.lax.fori_loop(0, size, unmark, 0)
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def compact_rows_pallas(dst, w, ts, size, read_ts=None, *,
+                        interpret: bool | None = None):
+    """Drop-in for ``ref.compact_rows_ref`` (same outputs, same order)."""
+    if interpret is None:
+        interpret = ops.interpret()
+    if read_ts is not None:  # MVCC filter applied before the scan
+        dst = jnp.where(ts <= jnp.asarray(read_ts, ts.dtype), dst, -1)
+    return _rows_call(_compact_kernel, 1, dst, w, ts, size, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("n_cap", "interpret"))
-def defrag_rows_pallas(dst, w, ts, size, *, n_cap: int | None = None,
-                       interpret: bool | None = None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def defrag_rows_pallas(dst, w, ts, size, *, interpret: bool | None = None):
     """Drop-in for ``ref.defrag_rows_ref`` (dedup mode only — the 'grow'
     policy's keep-everything variant stays on the jnp oracle). Returns
     (dst', w', ts', count, live) with live == count: dedup mode keeps
     exactly the live pairs."""
-    K, D = dst.shape
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if n_cap is None:
-        n_cap = 1 << 20
-    words = (n_cap + 31) // 32
-
-    grid = (K,)
-    row = lambda i: (i, 0)
-    out = pl.pallas_call(
-        _defrag_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, 1), row),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, D), row),
-            pl.BlockSpec((1, 1), row),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((K, D), dst.dtype),
-            jax.ShapeDtypeStruct((K, D), w.dtype),
-            jax.ShapeDtypeStruct((K, D), ts.dtype),
-            jax.ShapeDtypeStruct((K, 1), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((words,), jnp.uint32),
-                        pltpu.VMEM((words,), jnp.uint32),
-                        pltpu.VMEM((words,), jnp.uint32),
-                        pltpu.VMEM((words,), jnp.int32)],
-        interpret=interpret,
-    )(dst, w, ts, size.reshape(K, 1).astype(jnp.int32))
-    odst, ow, ots, ocnt = out
-    return odst, ow, ots, ocnt[:, 0], ocnt[:, 0]
+        interpret = ops.interpret()
+    odst, ow, ots, cnt = _rows_call(_defrag_kernel, 2, dst, w, ts, size,
+                                    interpret)
+    return odst, ow, ots, cnt, cnt

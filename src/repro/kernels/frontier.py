@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import ops
+
 __all__ = ["frontier_pallas"]
 
 
@@ -57,7 +59,7 @@ def _kernel(owner_ref, dst_ref, valid_ref, fbits_ref, out_ref):
 def frontier_pallas(owner, dst, valid, frontier_bits, visited_bits,
                     tile: int = 128, interpret: bool | None = None):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = ops.interpret()
     NB, BS = dst.shape
     tile = min(tile, NB)
     assert NB % tile == 0, "pad the pool to a multiple of the block tile"

@@ -1,30 +1,59 @@
-"""Jit'd public wrappers around the Pallas kernels with ref fallbacks.
+"""Jit'd public wrappers around the Pallas kernels, with jnp references.
 
-On this container (CPU) the Pallas TPU kernels execute in interpret mode;
-``impl='auto'`` picks interpret-Pallas only when explicitly requested so unit
-economics on CPU stay sane. On a real TPU build, 'pallas' is the default.
+Which implementation runs is decided here and nowhere else, at call (trace)
+time — importing this module never touches a backend:
+
+* ``impl='auto'`` takes the kernel's entry in ``TPU_IMPL`` when the backend
+  is a TPU, and the jnp reference (``kernels/ref.py``) everywhere else;
+* an explicit ``impl='pallas'`` or ``impl='ref'`` always wins;
+* a Pallas kernel runs compiled on a TPU and in interpret mode off it.
+
+``TPU_IMPL`` is a static table, never a runtime fallback: a kernel whose
+entry is ``'ref'`` has a documented reason (CHANGES.md) for staying off the
+TPU default.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
-import jax.numpy as jnp
 
 from . import ref as _ref
 
-_DEFAULT_IMPL = "ref"  # flipped to "pallas" on TPU backends at import time
-try:  # pragma: no cover - depends on runtime platform
-    if jax.default_backend() == "tpu":
-        _DEFAULT_IMPL = "pallas"
-except Exception:  # pragma: no cover
-    pass
+# Static per-kernel choice on a TPU backend.
+#  append      — compiles, but its grid visits every touched pool tile and
+#                loops over all B probes and B writes in each, so a uniform
+#                batch on a deployment-size pool moves nearly the whole pool
+#                through VMEM per batch; the jnp scatter + windowed probe
+#                stays the default until the kernel is redesigned.
+#  sort_lookup — Mosaic refuses it: "Loads are only allowed on VMEM and SMEM
+#                references" (it loads from ANY/HBM refs); off the store path.
+#  frontier    — Mosaic refuses it: "Cannot store scalars to VMEM"; off the
+#                store path.
+TPU_IMPL = {
+    "append": "ref",
+    "compact_rows": "pallas",
+    "defrag_rows": "pallas",
+    "sort_lookup": "ref",
+    "frontier": "ref",
+}
 
 
-def default_impl() -> str:
-    """The backend-selected kernel implementation ('ref' on CPU, 'pallas'
-    on TPU) — lets callers make the same static choice this module makes."""
-    return _DEFAULT_IMPL
+def platform() -> str:
+    """The backend the next trace runs on (queried at call time)."""
+    return jax.default_backend()
+
+
+def resolve(kernel: str, impl: str = "auto") -> str:
+    """'pallas' or 'ref' for ``kernel`` under the ``impl`` knob."""
+    if impl == "auto":
+        return TPU_IMPL[kernel] if platform() == "tpu" else "ref"
+    if impl not in ("pallas", "ref"):
+        raise ValueError(f"unknown kernel impl {impl!r}")
+    return impl
+
+
+def interpret() -> bool:
+    """Pallas interpret mode: exactly when the backend is not a TPU."""
+    return platform() != "tpu"
 
 
 def append_tile_rows(nb: int, tile: int = 128) -> int:
@@ -42,55 +71,52 @@ def append_edges(dst, w, ts, wblk, wlane, wval, wd, ww, wts,
     last-writer pair-liveness probe, bounded to the prefetched ``tiles``
     list (touched pool tiles; the ref oracle is dense and ignores it).
     See ref.append_ref."""
-    impl = _DEFAULT_IMPL if impl == "auto" else impl
-    if impl == "pallas":
+    if resolve("append", impl) == "pallas":
         from .append import append_pallas
         return append_pallas(dst, w, ts, wblk, wlane, wval, wd, ww, wts,
-                             pstart, psize, pv, tiles, n_touched)
+                             pstart, psize, pv, tiles, n_touched,
+                             interpret=interpret())
     return _ref.append_ref(dst, w, ts, wblk, wlane, wval, wd, ww, wts,
                            pstart, psize, pv)
 
 
 def compact_rows(dst, w, ts, size, read_ts=None, impl: str = "auto"):
     """Batched log compaction (paper Alg. 2). See ref.compact_rows_ref."""
-    impl = _DEFAULT_IMPL if impl == "auto" else impl
-    if impl == "pallas":
+    if resolve("compact_rows", impl) == "pallas":
         from .compact import compact_rows_pallas
-        return compact_rows_pallas(dst, w, ts, size, read_ts=read_ts)
+        return compact_rows_pallas(dst, w, ts, size, read_ts=read_ts,
+                                   interpret=interpret())
     return _ref.compact_rows_ref(dst, w, ts, size, read_ts=read_ts)
 
 
 def defrag_rows(dst, w, ts, size, keep_all: bool = False,
-                n_cap: int | None = None, impl: str = "auto"):
+                impl: str = "auto"):
     """Defrag row compactor: last-writer dedup + tombstone drop with
     destination-ASCENDING emission (the streaming rebuild's per-vertex
-    pass). ``n_cap`` is the destination-offset universe the kernel's
-    bitmaps must cover — callers pass the vertex-table capacity.
-    ``keep_all`` (the 'grow' policy) always runs the jnp oracle — it
-    keeps every version, which the bitmap kernel cannot express.
+    pass). ``keep_all`` (the 'grow' policy) always runs the jnp oracle — it
+    keeps every version, which the kernel does not express.
     See ref.defrag_rows_ref; returns (dst', w', ts', count, live)."""
-    impl = _DEFAULT_IMPL if impl == "auto" else impl
-    if impl == "pallas" and not keep_all:
+    if resolve("defrag_rows", impl) == "pallas" and not keep_all:
         from .compact import defrag_rows_pallas
-        return defrag_rows_pallas(dst, w, ts, size, n_cap=n_cap)
+        return defrag_rows_pallas(dst, w, ts, size, interpret=interpret())
     return _ref.defrag_rows_ref(dst, w, ts, size, keep_all=keep_all)
 
 
 def sort_lookup(pools, counts, keys, *, fanout_bits, bit_offsets,
                 impl: str = "auto"):
-    impl = _DEFAULT_IMPL if impl == "auto" else impl
-    if impl == "pallas":
+    if resolve("sort_lookup", impl) == "pallas":
         from .sort_lookup import sort_lookup_pallas
         return sort_lookup_pallas(pools, counts, keys, fanout_bits=fanout_bits,
-                                  bit_offsets=bit_offsets)
+                                  bit_offsets=bit_offsets,
+                                  interpret=interpret())
     return _ref.sort_lookup_ref(pools, counts, keys, fanout_bits=fanout_bits,
                                 bit_offsets=bit_offsets)
 
 
 def frontier_expand(owner, dst, valid, frontier_bits, visited_bits,
                     impl: str = "auto"):
-    impl = _DEFAULT_IMPL if impl == "auto" else impl
-    if impl == "pallas":
+    if resolve("frontier", impl) == "pallas":
         from .frontier import frontier_pallas
-        return frontier_pallas(owner, dst, valid, frontier_bits, visited_bits)
+        return frontier_pallas(owner, dst, valid, frontier_bits, visited_bits,
+                               interpret=interpret())
     return _ref.frontier_ref(owner, dst, valid, frontier_bits, visited_bits)

@@ -18,9 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUMemorySpace -> MemorySpace in newer pallas releases
-_ANY_MEMSPACE = getattr(pltpu, "MemorySpace",
-                        getattr(pltpu, "TPUMemorySpace", None)).ANY
+from . import ops
 
 __all__ = ["sort_lookup_pallas"]
 
@@ -68,7 +66,7 @@ def sort_lookup_pallas(pools, counts, keys, *, fanout_bits, bit_offsets,
     """(B, 2) uint32 keys -> int32 offsets. B must be a multiple of ``tile``
     (callers pad; the facade's batches are power-of-two sized)."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = ops.interpret()
     layers = len(fanout_bits)
     B = keys.shape[0]
     tile = min(tile, B)
@@ -78,7 +76,7 @@ def sort_lookup_pallas(pools, counts, keys, *, fanout_bits, bit_offsets,
     in_specs = [pl.BlockSpec((tile, 2), lambda i: (i, 0))]
     # node pools stay unblocked in ANY memory (HBM); scalar loads chase them
     for _ in range(layers):
-        in_specs.append(pl.BlockSpec(memory_space=_ANY_MEMSPACE))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY))
 
     out = pl.pallas_call(
         _make_kernel(layers, tuple(fanout_bits), tuple(bit_offsets), tile),

@@ -20,12 +20,10 @@ from typing import Dict, Tuple
 
 
 def cost_dict(compiled) -> Dict[str, float]:
-    """Normalized ``compiled.cost_analysis()``: newer jax returns a dict,
-    0.4.x returns a one-element list of dicts (and None on some backends)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()`` as a dict (empty where the backend
+    reports none)."""
+    return compiled.cost_analysis() or {}
+
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")

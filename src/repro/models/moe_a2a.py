@@ -26,7 +26,6 @@ from typing import Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .layers import _load_balance_loss
@@ -113,10 +112,10 @@ def moe_ffn_a2a(x, router_w, w1, w3, w2, *, top_k: int,
     x_spec = P(tok if batch_ok and tok else None, None, None)
     w_spec = P(exp, None, tp)
     w2_spec = P(exp, tp, None)
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(), w_spec, w_spec, w2_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, w1, w3, w2)
     return out

@@ -106,7 +106,8 @@ def main(argv=None) -> int:
     if args._child:
         return _child(args)
 
-    from repro.storage import recover
+    # the parent stays off JAX until the child has died: one process per
+    # chip, and the child needs it
     rng = np.random.default_rng(args.seed + 1000)
     n_batches = (args.ops + args.batch - 1) // args.batch
     kill = args.kill_batch if args.kill_batch is not None else int(
@@ -128,6 +129,7 @@ def main(argv=None) -> int:
         f"child should die by SIGKILL, got rc={proc.returncode}\n" \
         f"{proc.stderr[-2000:]}"
 
+    from repro.storage import recover
     store, report = recover(workdir, _mk_store)
     batches = _stream(args.seed, args.ops, args.batch)
     survived = report["last_seq"] + 1          # seqs are batch-aligned
@@ -169,4 +171,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime import init_compile_cache
+    init_compile_cache()
     sys.exit(main())

@@ -1,23 +1,9 @@
-import os
-import sys
-
 import numpy as np
 import pytest
 
 # NOTE: no XLA_FLAGS here on purpose — tests must see the real (1-device)
 # topology; only launch/dryrun*.py force placeholder devices (and the
 # multishard test does so in a subprocess).
-
-# hypothesis is an optional dep (pyproject test extras). When absent, install
-# the deterministic mini stand-in BEFORE test modules import it, so property
-# tests run with seeded examples instead of erroring at collection.
-sys.path.insert(0, os.path.dirname(__file__))
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - depends on environment
-    import _mini_hypothesis
-    sys.modules["hypothesis"] = _mini_hypothesis
-    sys.modules["hypothesis.strategies"] = _mini_hypothesis.strategies
 
 
 def pytest_configure(config):

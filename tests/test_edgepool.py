@@ -1,5 +1,7 @@
 """Snapshot-log edge storage vs an ordered Python oracle — the paper's core
 semantics (insert/update/delete, compaction, MVCC) under property testing."""
+import collections
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -47,6 +49,33 @@ def test_mixed_stream_matches_oracle(policy, ops):
         assert set(got) == set(exp)
         for k in exp:
             assert got[k] == pytest.approx(exp[k])
+
+
+@settings(max_examples=6, deadline=None)
+@pytest.mark.parametrize("policy", ["snaplog", "grow", "sorted"])
+@given(ops=ops_strategy, chunk=st.sampled_from([7, 64]))
+def test_rows_past_next_block_stay_empty(policy, ops, chunk):
+    """New extents are never initialized: every write lands below
+    ``next_block``, so the rows past it stay as a fresh pool holds them —
+    through appends, compactions, vertex deletes and rebuilds."""
+    g = mk(policy, pool_blocks=512, dmax=64, k_max=4)
+
+    def check():
+        pool = g.state.pool
+        nxt = int(pool.next_block)
+        assert (np.asarray(pool.dst)[nxt:] == -1).all()
+        assert (np.asarray(pool.weight)[nxt:] == 0).all()
+        assert (np.asarray(pool.ts)[nxt:] == 0).all()
+
+    for lo in range(0, len(ops), chunk):
+        part = ops[lo:lo + chunk]
+        g.apply_ops(np.array([o[0] for o in part], np.uint64),
+                    np.array([o[1] for o in part], np.uint64),
+                    np.array([o[2] for o in part], np.float32))
+        check()
+    g.delete_vertices([ops[0][0]])
+    g.defrag()
+    check()
 
 
 def test_compaction_triggers_and_preserves(rng):
@@ -254,6 +283,34 @@ def test_compaction_fold_keeps_over_window_vertex_exact():
     # 20 - 1 delete + 2 inserts = 21, updates don't change the count
     assert int(g.state.pool.live_m) == 21
     assert g.num_edges == int(g.snapshot().m) == 21
+
+
+@pytest.mark.parametrize("impl", ["pallas", "ref"])
+def test_explicit_impl_reaches_kernel_dispatch(impl, monkeypatch):
+    """``append_impl`` / ``compact_impl`` set on the graph decide which
+    kernel the jitted step, the reads and the rebuild trace — on any
+    backend — and the dense append oracle never serves the store."""
+    from repro.kernels import append as ka, compact as kc, ref as kr
+    calls = collections.Counter()
+    for mod, name in ((ka, "append_pallas"), (kc, "compact_rows_pallas"),
+                      (kc, "defrag_rows_pallas"), (kr, "append_ref")):
+        def spy(*a, _fn=getattr(mod, name), _name=name, **k):
+            calls[_name] += 1
+            return _fn(*a, **k)
+        monkeypatch.setattr(mod, name, spy)
+    g = RadixGraph(n_max=80, key_bits=16, expected_n=40, batch=32,
+                   pool_blocks=136, block_size=8, dmax=64, k_max=8,
+                   append_impl=impl, compact_impl=impl)
+    dst = np.arange(1, 41, dtype=np.uint64)
+    g.add_edges(np.zeros(20, np.uint64), dst[:20])
+    g.add_edges(np.zeros(20, np.uint64), dst[20:])   # vertex 0 overflows
+    g.defrag()
+    got = g.neighbors([0])[0][0]
+    assert sorted(got.tolist()) == dst.tolist()
+    assert calls["append_ref"] == 0
+    for name in ("append_pallas", "compact_rows_pallas",
+                 "defrag_rows_pallas"):
+        assert (calls[name] > 0) == (impl == "pallas"), (name, calls)
 
 
 def test_pallas_append_path_matches_ref_path(rng):

@@ -8,6 +8,7 @@ from repro.core import sort as S
 from repro.core.keys import pack_keys
 from repro.core.sort import SortSpec
 from repro.core.sort_optimizer import optimize_sort
+from repro.kernels import ops
 from repro.kernels import ref as R
 from repro.kernels.append import append_pallas, append_tile_rows
 from repro.kernels.compact import compact_rows_pallas, defrag_rows_pallas
@@ -26,7 +27,7 @@ def test_compact_kernel_sweep(K, D, wdtype, rng):
     a = R.compact_rows_ref(jnp.asarray(dst), jnp.asarray(w, wdtype),
                            jnp.asarray(ts), jnp.asarray(size))
     b = compact_rows_pallas(jnp.asarray(dst), jnp.asarray(w, wdtype),
-                            jnp.asarray(ts), jnp.asarray(size), n_cap=n_cap)
+                            jnp.asarray(ts), jnp.asarray(size))
     for x, y in zip(a, b):
         np.testing.assert_allclose(np.asarray(x, np.float32),
                                    np.asarray(y, np.float32))
@@ -44,7 +45,7 @@ def test_compact_kernel_read_ts(seed, size_hint):
     rt = int(rng.integers(0, K * D))
     a = R.compact_rows_ref(*map(jnp.asarray, (dst, w, ts, size)), read_ts=rt)
     b = compact_rows_pallas(*map(jnp.asarray, (dst, w, ts, size)),
-                            read_ts=rt, n_cap=64)
+                            read_ts=rt)
     for x, y in zip(a, b):
         assert np.array_equal(np.asarray(x), np.asarray(y))
 
@@ -82,8 +83,7 @@ def test_defrag_rows_kernel_sweep(K, D, rng):
     ts = rng.permutation(K * D).reshape(K, D).astype(np.int32)
     size = rng.integers(0, D + 1, (K,)).astype(np.int32)
     a = R.defrag_rows_ref(*map(jnp.asarray, (dst, w, ts, size)))
-    b = defrag_rows_pallas(*map(jnp.asarray, (dst, w, ts, size)),
-                           n_cap=n_cap)
+    b = defrag_rows_pallas(*map(jnp.asarray, (dst, w, ts, size)))
     for x, y in zip(a, b):
         assert np.array_equal(np.asarray(x), np.asarray(y))
 
@@ -198,3 +198,19 @@ def test_frontier_kernel(seed):
     a = R.frontier_ref(*map(jnp.asarray, (owner, dst, valid, f, v)))
     b = frontier_pallas(*map(jnp.asarray, (owner, dst, valid, f, v)), tile=8)
     assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+def test_kernel_impl_resolves_from_one_static_table(platform, monkeypatch):
+    """'auto' follows the backend: the static TPU table on a TPU, the jnp
+    references elsewhere; explicit knobs always win, and Pallas runs in
+    interpret mode exactly off-TPU."""
+    monkeypatch.setattr(ops, "platform", lambda: platform)
+    for kernel, tpu_impl in ops.TPU_IMPL.items():
+        want = tpu_impl if platform == "tpu" else "ref"
+        assert ops.resolve(kernel) == want
+        assert ops.resolve(kernel, "pallas") == "pallas"
+        assert ops.resolve(kernel, "ref") == "ref"
+    assert ops.interpret() == (platform != "tpu")
+    with pytest.raises(ValueError):
+        ops.resolve("append", "mosaic")
