@@ -26,6 +26,7 @@ apply program holds a Pallas kernel (``tpu_custom_call``). The last line is
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import pathlib
 import sys
@@ -43,14 +44,15 @@ BLOCK = 16
 
 # The target scale is 2^20 vertices; each power-of-two cut from it, and why.
 # The edge-update step costs a roughly fixed time per 4096-entry batch (its
-# gathers and scatters have static sizes), and 2^20 vertices take ~8,900
-# batches: on one TPU v5e the 2^20 insert stream alone ran 1,407 s at
-# ~155 ms per batch, against the run's 1,200 s limit.
+# gathers and scatters have static sizes): 2^20 vertices take ~8,900
+# batches, 2^19 ~4,450. The run prints its steady ingest rate and what the
+# 2^20 stream would take at it.
 TARGET_LOG2_V = 20
-CUTS = {19: "the 2^20 ingest outlasts the 1200 s run limit on one TPU v5e",
-        18: "at ~155 ms per batch the 2^19 ingest alone takes ~690 s, too "
-            "close to the 1200 s limit once compilation and analytics are "
-            "added"}
+CUTS = {19: "a 2^19 run on one TPU v5e ingested 48.8 s per 2^20 inserts "
+            "after compilation (~95 ms per 4096-entry batch), so the 2^20 "
+            "insert stream alone takes ~781 s; with the step's ~140 s "
+            "compile, the tail, the rebuild and the analytics the command "
+            "passes the 1200 s run limit"}
 DEFAULT_LOG2_V = min(CUTS, default=TARGET_LOG2_V)
 
 
@@ -207,6 +209,19 @@ def compile_aside(store, kw: dict, probe_id: int) -> list:
     return futures
 
 
+def rebuild(store) -> None:
+    """Force one rebuild of a LocalStore's pool through its graph's
+    explicit defrag, after checking that it takes the streaming path (the
+    one ``defrag_rows`` runs in) rather than the dense fallback."""
+    from repro.core import edgepool as ep
+    g = store.graph
+    _, _, streams = ep.defrag_segments(g.pool_spec, g.state.vt)
+    assert bool(streams), "the forced rebuild would take the dense path"
+    d0 = g.num_defrags
+    g.defrag()
+    assert g.num_defrags == d0 + 1, "the forced rebuild did not run"
+
+
 def apply_program_text(store) -> str:
     """StableHLO of the store's per-batch apply program, lowered for the
     store's own state and batch shapes."""
@@ -246,6 +261,7 @@ def run(log2_v: int = 20, edgefactor: int = 16, n_shards: int = 1,
         f"{len(s['tu'])} tail ops; store {kw}")
 
     t0 = time.perf_counter()
+    t["rebuild_s"] = 0.0
     aside = []
     if n_shards == 1:
         store = make_store("local", **kw)
@@ -254,16 +270,29 @@ def run(log2_v: int = 20, edgefactor: int = 16, n_shards: int = 1,
         store = make_store("sharded", devices=jax.devices()[:n_shards], **kw)
         pspec = store.pspec
         aside = compile_aside(store, kw, int(ids[0]))
-    for lo in range(0, E, chunk):
-        hi = min(E, lo + chunk)
+    # one chip: rebuild the pool once, early, while nearly every extent is
+    # one block and the rebuild streams; the later checks cover its output
+    rebuild_at = E // 8
+    bounds = sorted(set(range(0, E, chunk)) | {rebuild_at, E})
+    marks = []                         # (inserts applied, host clock)
+    for lo, hi in zip(bounds, bounds[1:]):
         r = store.apply(OpBatch.edges(ids[s["su"][lo:hi]],
                                       ids[s["sv"][lo:hi]], s["w"][lo:hi]))
         assert r.dropped == 0, f"inserts dropped at op {lo}: {r}"
         log(f"ingest: {hi} of {E} inserts applied")
+        if n_shards == 1 and hi == rebuild_at:
+            t1 = time.perf_counter()
+            rebuild(store)
+            t["rebuild_s"] = time.perf_counter() - t1
+            log(f"rebuild: streaming rebuild after {hi} inserts")
+        marks.append((hi, time.perf_counter()))
     r = store.apply(OpBatch.edges(ids[s["tu"]], ids[s["tv"]], s["tw"]))
     assert r.dropped == 0, f"tail ops dropped: {r}"
     jax.block_until_ready(store.capture().state)
-    t["ingest_s"] = time.perf_counter() - t0
+    t["ingest_s"] = time.perf_counter() - t0 - t["rebuild_s"]
+    if len(marks) >= 3:    # the first chunk holds the step's compilation
+        (n0, c0), (n1, c1) = marks[1], marks[-1]
+        t["steady_ingest_s_per_insert"] = (c1 - c0) / (n1 - n0)
     dispatches = store.stats["super_batches"]
     log(f"ingest: {E + len(s['tu'])} ops in {dispatches} dispatches, "
         f"defrags={store.stats['defrags']}")
@@ -274,6 +303,15 @@ def run(log2_v: int = 20, edgefactor: int = 16, n_shards: int = 1,
         t["side_compile_wait_s"] = time.perf_counter() - t0
         log(f"side compiles: {len(aside)} programs ready")
 
+    # persistent compile cache hits and misses from here on: they show
+    # whether the side compiles reached the ingested store
+    cache = collections.Counter()
+
+    def count_cache(event, **_):
+        if event.startswith("/jax/compilation_cache/cache_"):
+            cache[event.rsplit("_", 1)[-1]] += 1
+
+    jax.monitoring.register_event_listener(count_cache)
     rng = np.random.default_rng(seed + 1)
     sample = rng.choice(V, min(N_SAMPLE, V), replace=False)
     t0 = time.perf_counter()
@@ -310,6 +348,9 @@ def run(log2_v: int = 20, edgefactor: int = 16, n_shards: int = 1,
     err = float(np.max(np.abs(pr - want[live]) / want[live]))
     assert err <= PR_RTOL, f"PageRank max relative error {err} > {PR_RTOL}"
     log(f"pagerank: max relative error {err:.3e} (limit {PR_RTOL})")
+    jax.monitoring.unregister_event_listener(count_cache)
+    log(f"compile cache during reads and analytics: {cache['hits']} hits, "
+        f"{cache['misses']} misses")
 
     if n_shards > 1:
         devs = {d for d in jax.devices()[:n_shards]}
@@ -324,6 +365,7 @@ def run(log2_v: int = 20, edgefactor: int = 16, n_shards: int = 1,
         ("defrag_rows", pspec.compact_impl))}
     text = apply_program_text(store)
     rec = dict(phase_host_wall_s=t, impl=impls, ingest_dispatches=dispatches,
+               defrags=store.stats["defrags"], compile_cache=dict(cache),
                apply_program_has_tpu_custom_call="tpu_custom_call" in text)
     stats = jax.devices()[0].memory_stats() or {}
     rec["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
@@ -360,6 +402,12 @@ def main(argv=None) -> int:
         log(f"host wall seconds {k}: {v!r}")
     log(f"host wall seconds per ingest dispatch, compilation included: "
         f"{rec['phase_host_wall_s']['ingest_s'] / rec['ingest_dispatches']!r}")
+    per = rec["phase_host_wall_s"].get("steady_ingest_s_per_insert")
+    if per:
+        full = args.edgefactor << TARGET_LOG2_V
+        log(f"steady ingest after compilation: {per * (1 << 20)!r} host wall "
+            f"s per 2^20 inserts; the 2^{TARGET_LOG2_V}-vertex target's "
+            f"{full} inserts would take {per * full!r} s")
     log(f"peak device bytes in use: {rec['peak_bytes_in_use']}")
     log(f"kernel impl: {rec['impl']}")
     log(f"apply program has tpu_custom_call: "

@@ -22,7 +22,6 @@ TPU adaptation: per-vertex ``malloc``'d edge arrays become contiguous block
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import NamedTuple, Tuple
 
@@ -684,6 +683,25 @@ def _defrag_stream(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
                              active_row)
 
 
+def defrag_segments(spec: PoolSpec, vt: VertexTable):
+    """The streaming rebuild's size segments for ``vt``'s live extents:
+    ``(tiers, masks, stream_ok)``. ``stream_ok`` holds when every extent
+    fits a segment within its budget; otherwise ``defrag`` takes the
+    dense path."""
+    tiers = _defrag_tiers(spec, vt.size.shape[0])
+    live_row = (vt.del_time == 0) & (vt.start_block >= 0)
+    sz = jnp.where(live_row, vt.size, 0)
+    masks, fits = [], []
+    prev = 0
+    for W, Bj in tiers:
+        m = live_row & (sz > prev) & (sz <= W)
+        masks.append(m)
+        fits.append(jnp.sum(m.astype(jnp.int32)) <= Bj)
+        prev = W
+    stream_ok = jnp.all(jnp.stack(fits)) & (jnp.max(sz) <= tiers[-1][0])
+    return tiers, masks, stream_ok
+
+
 def defrag(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
            incoming: jnp.ndarray | None = None):
     """Rebuild the pool compactly in vertex order (CSR-like layout).
@@ -704,17 +722,7 @@ def defrag(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
         incoming = jnp.zeros((n_cap,), jnp.int32)
     if spec.defrag_impl == "dense":
         return _defrag_dense(spec, pool, vt, incoming)
-    tiers = _defrag_tiers(spec, n_cap)
-    live_row = (vt.del_time == 0) & (vt.start_block >= 0)
-    sz = jnp.where(live_row, vt.size, 0)
-    masks, fits = [], []
-    prev = 0
-    for W, Bj in tiers:
-        m = live_row & (sz > prev) & (sz <= W)
-        masks.append(m)
-        fits.append(jnp.sum(m.astype(jnp.int32)) <= Bj)
-        prev = W
-    stream_ok = jnp.all(jnp.stack(fits)) & (jnp.max(sz) <= tiers[-1][0])
+    tiers, masks, stream_ok = defrag_segments(spec, vt)
     return jax.lax.cond(
         stream_ok,
         lambda args: _defrag_stream(spec, args[0], args[1], incoming,
@@ -926,27 +934,12 @@ def apply_edge_updates(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
         probe_blind = jnp.zeros((), bool)
     else:
         Wp = min(spec.probe_width, spec.dmax)
-
-        def probe(width):
-            d_e, w_e, t_e, _ = _gather_vertex_entries(spec, pool, vt,
-                                                      probe_u, width)
-            t_match = jnp.where(d_e == v2[:, None], t_e, 0)  # clock from 1
-            newest = jnp.argmax(t_match, axis=1)
-            return (jnp.max(t_match, axis=1) > 0) & \
-                (w_e[jnp.arange(B), newest] != 0)
-
-        # the gather costs B x width: probe at the narrowest of Wp, Wp/2,
-        # ... (down to two blocks) that covers every probed extent of this
-        # batch — entries past an extent's size are masked, so any covering
-        # width gives the same answer
-        widths = [Wp]
-        while widths[0] > 2 * bs:
-            widths.insert(0, widths[0] // 2)
-        need = jnp.max(jnp.where(probe_u >= 0, jnp.minimum(p_sz, Wp), 0))
-        pick = jnp.sum((jnp.asarray(widths[:-1], jnp.int32) < need)
-                       .astype(jnp.int32))
-        win_was_live = jax.lax.switch(
-            pick, [functools.partial(probe, w) for w in widths])
+        d_e, w_e, t_e, _ = _gather_vertex_entries(spec, pool, vt,
+                                                  probe_u, Wp)
+        t_match = jnp.where(d_e == v2[:, None], t_e, 0)  # clock starts at 1
+        newest = jnp.argmax(t_match, axis=1)
+        win_was_live = (jnp.max(t_match, axis=1) > 0) & \
+            (w_e[jnp.arange(B), newest] != 0)
         probe_blind = jnp.any((probe_u >= 0) & (p_sz > Wp))
         pool = _scatter_entries(pool, tgt_blk, slot % bs, op_ok, sv, sw_, sts)
 

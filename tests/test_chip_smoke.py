@@ -24,6 +24,7 @@ def smoke():
 def test_smoke_checks_pass_on_small_graph(smoke):
     rec = smoke.run(log2_v=8, edgefactor=4, chunk=1 << 9)
     assert rec["impl"]["append"] in ("ref", "pallas")
+    assert rec["defrags"] >= 1        # the forced rebuild ran and was checked
     assert set(rec["phase_host_wall_s"]) >= {"ingest_s", "reads_s",
                                              "bfs_s", "pagerank_s"}
 
