@@ -106,7 +106,14 @@ class LocalStore:
     of snapshots/analytics; analytics cost scales with it, so benchmarks
     pass a tight bound). The wrapped graph stays reachable as ``.graph``
     for backend-specific extras (MVCC version labels, defrag, memory
-    accounting)."""
+    accounting).
+
+    Each call records host spans (``jax.profiler.TraceAnnotation``, so
+    only while a profiler trace runs), each with the call's request number
+    ``req``: ``graph.apply`` (``.stage``, ``.dispatch``, ``.wait``, from
+    ``RadixGraph``), ``graph.capture``, ``graph.read`` (``.dispatch``,
+    ``.wait``, ``.fetch``) and ``graph.analytics`` (``.snapshot``,
+    ``.run``, ``.result``)."""
 
     backend = "local"
     supported_ops = frozenset(("edges", "add_vertices", "delete_vertices"))
@@ -118,23 +125,38 @@ class LocalStore:
         self.m_cap = m_cap or self.graph.pool_spec.capacity_entries
         self.max_delta_frac = max_delta_frac
         self._seq = 0
+        self._req = 0           # request number of the trace spans
         self._restore_gen = 0   # bumped by every restore(): epoch handles
         #                         captured before it are no longer delta-safe
+        # host-clock counters: ``host_stage_ms`` staging + dispatch of the
+        # flushes, ``flush_wait_ms`` the flushes' waits for their drop
+        # counts; ``read_fetch_ms`` / ``read_fetch_bytes`` the reads'
+        # device-to-host copies
         self.stats = dict(ops_applied=0, ops_dropped=0, defrags=0,
                           defrag_ms=0.0, defrag_host_ms=0.0,
                           defrag_sync_ms=0.0, tiles_scanned=0,
                           flushes=0, super_batches=0,
-                          host_stage_ms=0.0, device_sync_ms=0.0)
+                          host_stage_ms=0.0, flush_wait_ms=0.0,
+                          read_fetch_ms=0.0, read_fetch_bytes=0)
+
+    def _request(self) -> int:
+        self._req += 1
+        return self._req
 
     # ---- mutation ----
     def apply(self, batch: OpBatch) -> ApplyResult:
         if len(batch) == 0:
             return ApplyResult(0, 0)
+        req = self._request()
+        with jax.profiler.TraceAnnotation("graph.apply", req=req):
+            return self._apply(batch, req)
+
+    def _apply(self, batch: OpBatch, req: int) -> ApplyResult:
         self._seq += 1
         g = self.graph
         if batch.kind == "edges":
             d0 = g.dropped_ops
-            g.apply_ops(batch.src, batch.dst, batch.weight)
+            g.apply_ops(batch.src, batch.dst, batch.weight, req)
             res = ApplyResult(len(batch), g.dropped_ops - d0)
         else:
             o0 = int(g.state.vt.overflow)
@@ -155,15 +177,17 @@ class LocalStore:
         self.stats["flushes"] = g.pipe_flushes
         self.stats["super_batches"] = g.pipe_super_batches
         self.stats["host_stage_ms"] = round(g.pipe_stage_ms, 3)
-        self.stats["device_sync_ms"] = round(g.pipe_sync_ms, 3)
+        self.stats["flush_wait_ms"] = round(g.pipe_wait_ms, 3)
         return res
 
     # ---- epochs ----
     def capture(self) -> Epoch:
-        # exempt the captured state from steady-state buffer donation
-        self.graph.pin_live_state()
-        return Epoch(self.graph.state, self._seq,
-                     cache={"gen": self._restore_gen})
+        with jax.profiler.TraceAnnotation("graph.capture",
+                                          req=self._request()):
+            # exempt the captured state from steady-state buffer donation
+            self.graph.pin_live_state()
+            return Epoch(self.graph.state, self._seq,
+                         cache={"gen": self._restore_gen})
 
     def clock(self, at: Optional[Epoch] = None) -> int:
         state = at.state if at is not None else self.graph.state
@@ -234,29 +258,46 @@ class LocalStore:
         return snap
 
     def read(self, op: ReadOp, at: Optional[Epoch] = None):
+        req = self._request()
+        with jax.profiler.TraceAnnotation("graph.read", req=req):
+            return self._read(op, self._state(at), at, req)
+
+    def _fetch(self, tree, req: int):
+        """Copy a read's device arrays to the host, inside the
+        ``graph.read.fetch`` span, counted in ``read_fetch_ms`` and
+        ``read_fetch_bytes``."""
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("graph.read.fetch", req=req):
+            host = jax.tree.map(np.asarray, tree)
+        self.stats["read_fetch_ms"] += (time.perf_counter() - t) * 1000.0
+        self.stats["read_fetch_bytes"] += sum(
+            a.nbytes for a in jax.tree.leaves(host))
+        return host
+
+    def _read(self, op: ReadOp, state, at: Optional[Epoch], req: int):
         g = self.graph
-        state = self._state(at)
-        if op.kind == "lookup":
-            off = self._per_key(state, op.ids, lambda s, k: _lookup(
-                g.sort_spec, g.pool_spec, s, k))
-            return off >= 0
-        if op.kind == "degree":
-            return self._per_key(state, op.ids, lambda s, k: _degree(
-                g.sort_spec, g.pool_spec, s, k))
-        if op.kind == "neighbors":
+        if op.kind in ("lookup", "degree", "neighbors"):
             width = op.width or g.pool_spec.dmax
-            ds, ws, cs = [], [], []
-            for keys, _ in g._key_batches(op.ids):
-                bd, bw, _, bcnt = _neighbors(g.sort_spec, g.pool_spec,
-                                             state, keys, width, None)
-                ds.append(np.asarray(bd))
-                ws.append(np.asarray(bw))
-                cs.append(np.asarray(bcnt))
+            with jax.profiler.TraceAnnotation("graph.read.dispatch", req=req):
+                outs = []
+                for keys, _ in g._key_batches(op.ids):
+                    if op.kind == "neighbors":
+                        bd, bw, _, bcnt = _neighbors(g.sort_spec, g.pool_spec,
+                                                     state, keys, width, None)
+                        outs.append((bd, bw, bcnt))
+                    else:
+                        fn = _lookup if op.kind == "lookup" else _degree
+                        outs.append(fn(g.sort_spec, g.pool_spec, state, keys))
+            with jax.profiler.TraceAnnotation("graph.read.wait", req=req):
+                jax.block_until_ready(outs)
             n = len(np.asarray(op.ids))
-            d = np.concatenate(ds)[:n]
-            w = np.concatenate(ws)[:n]
-            cnt = np.concatenate(cs)[:n]
-            ids_np = np.asarray(state.vt.ids)
+            if op.kind != "neighbors":
+                out = self._fetch(outs, req)
+                off = (np.concatenate(out)[:n] if out
+                       else np.zeros((0,), np.int32))
+                return off >= 0 if op.kind == "lookup" else off
+            outs, ids_np = self._fetch((outs, state.vt.ids), req)
+            d, w, cnt = (np.concatenate(x)[:n] for x in zip(*outs))
             oc = np.clip(d, 0, ids_np.shape[0] - 1)
             gids = unpack_keys(ids_np[oc])
             return [(gids[i, :cnt[i]], w[i, :cnt[i]]) for i in range(n)]
@@ -315,37 +356,50 @@ class LocalStore:
                          _reason: str = "") -> AnalyticsResult:
         """From-scratch run, answered as an ``AnalyticsResult`` whose
         ``raw`` per-row values seed a later ``analytics_advance``."""
+        req = self._request()
+        with jax.profiler.TraceAnnotation("graph.analytics", req=req):
+            return self._analytics_result(op, at, _reason, req)
+
+    def _analytics_result(self, op: AnalyticsOp, at: Optional[Epoch],
+                          _reason: str, req: int) -> AnalyticsResult:
         spec = analytics_spec(op.name)
         state = self._state(at)
-        snap = self._snap(at)
-        params = dict(op.params)
-        dyn, _rows, absent_source = self._resolve_dyn(spec, state, params)
-        n_cap = snap.indptr.shape[0] - 1
-        iters = 0
-        if absent_source:
-            vals = np.full((n_cap,), spec.absent)
-        else:
-            args = [a[0] if isinstance(a, tuple) else a for a in dyn]
-            vals = spec.single(snap, *args, **params)
-            if isinstance(vals, tuple):      # convergence entries: (v, it)
-                vals, it = vals
-                iters = int(np.asarray(it))
-        seq = at.seq if at is not None else self._seq
-        if spec.result == "scalar":
-            v = np.asarray(vals).item()
-            return AnalyticsResult(v, seq, "scratch", iters, _reason, v, at)
-        if spec.result == "per_query":
-            out = np.asarray(vals).copy()
-            for a in dyn:
-                if isinstance(a, tuple):
-                    out[np.asarray(a[1]) < 0] = 0   # absent queries -> 0
-            return AnalyticsResult(out, seq, "scratch", iters, _reason,
-                                   None, at)
-        if spec.canonical_single is not None:
-            vals = spec.canonical_single(vals, snap)
-        raw = np.asarray(vals)
-        return AnalyticsResult(self._per_vertex_value(raw, snap), seq,
-                               "scratch", iters, _reason, raw, at)
+        with jax.profiler.TraceAnnotation("graph.analytics.snapshot",
+                                          req=req):
+            snap = self._snap(at)
+        with jax.profiler.TraceAnnotation("graph.analytics.run", req=req):
+            params = dict(op.params)
+            dyn, _rows, absent_source = self._resolve_dyn(spec, state,
+                                                          params)
+            n_cap = snap.indptr.shape[0] - 1
+            iters = 0
+            if absent_source:
+                vals = np.full((n_cap,), spec.absent)
+            else:
+                args = [a[0] if isinstance(a, tuple) else a for a in dyn]
+                vals = spec.single(snap, *args, **params)
+                if isinstance(vals, tuple):      # convergence entries: (v, it)
+                    vals, it = vals
+                    iters = int(np.asarray(it))
+                jax.block_until_ready(vals)
+        with jax.profiler.TraceAnnotation("graph.analytics.result", req=req):
+            seq = at.seq if at is not None else self._seq
+            if spec.result == "scalar":
+                v = np.asarray(vals).item()
+                return AnalyticsResult(v, seq, "scratch", iters, _reason, v,
+                                       at)
+            if spec.result == "per_query":
+                out = np.asarray(vals).copy()
+                for a in dyn:
+                    if isinstance(a, tuple):
+                        out[np.asarray(a[1]) < 0] = 0   # absent queries -> 0
+                return AnalyticsResult(out, seq, "scratch", iters, _reason,
+                                       None, at)
+            if spec.canonical_single is not None:
+                vals = spec.canonical_single(vals, snap)
+            raw = np.asarray(vals)
+            return AnalyticsResult(self._per_vertex_value(raw, snap), seq,
+                                   "scratch", iters, _reason, raw, at)
 
     def _csr(self, at: Epoch) -> ed.HostCsr:
         h = at.cache.get("hcsr")
@@ -502,7 +556,8 @@ class ShardedStore:
                           defrag_ms=0.0, defrag_host_ms=0.0,
                           defrag_sync_ms=0.0, tiles_scanned=0,
                           flushes=0, super_batches=0,
-                          host_stage_ms=0.0, device_sync_ms=0.0)
+                          host_stage_ms=0.0, flush_wait_ms=0.0)
+        self._req = 0           # request number of the trace spans
 
     @property
     def state(self):
@@ -610,6 +665,16 @@ class ShardedStore:
                 "registration sync); use LocalStore for vertex CRUD")
         if len(batch) == 0:
             return ApplyResult(0, 0)
+        self._req += 1
+        with jax.profiler.TraceAnnotation("graph.apply", req=self._req):
+            return self._apply(batch, self._req)
+
+    def _stage(self, batch: OpBatch) -> list:
+        """The flush staged once: interleaved, padded, packed and put on
+        the device as super-batches, each a list of flat (B, ...) batch
+        tuples or, fused, one stacked (k, B, ...) tuple. The ragged tail
+        ships at its true depth k' < K (whole-batch padding would advance
+        the pool clock and break parity with the sequential path)."""
         src, dst, w = batch.src, batch.dst, batch.weight
         if self.undirected:
             src, dst, w = interleave_undirected(src, dst, w)
@@ -618,52 +683,63 @@ class ShardedStore:
         N = len(src)
         NB = (N + B - 1) // B
         K = max(1, int(self.pipeline_depth))
-        t0 = time.perf_counter()
-        # stage the whole flush once, then dispatch (k, B, ...) super-batches
-        # ASYNCHRONOUSLY — no np.asarray() per batch; the ragged tail ships
-        # at its true depth k' < K (whole-batch padding would advance the
-        # pool clock and break parity with the sequential path)
         psk = np.zeros((NB * B, 2), np.uint32)
         pdk = np.zeros((NB * B, 2), np.uint32)
         pw = np.zeros((NB * B,), np.float32)
         mask = np.zeros((NB * B,), bool)
         psk[:N], pdk[:N], pw[:N], mask[:N] = sk, dk, w, True
-        drops = []
+        groups = []
         i = 0
         while i < NB:
             k = min(K, NB - i)
             lo, hi = i * B, (i + k) * B
             if k > 1 and self.fuse_scan:
-                # opt-in fused entry: k batches as ONE lax.scan program.
-                # (Slower than k flat donated dispatches on XLA CPU — the
-                # loop-carried pool scatters lose the in-place-update
-                # optimization — but it is the single-program artifact the
-                # dryrun lowers and the parity suite certifies.)
-                donate = self.donate_steady_state and \
-                    (self.state is not self._pinned)
-                fn = self.apply_program(donate=donate, depth=k)
-                self.state, d = fn(
-                    self.state,
-                    jnp.asarray(psk[lo:hi].reshape(k, B, 2)),
-                    jnp.asarray(pdk[lo:hi].reshape(k, B, 2)),
-                    jnp.asarray(pw[lo:hi].reshape(k, B)),
-                    jnp.asarray(mask[lo:hi].reshape(k, B)))
-                drops.append(d)             # device array — no sync here
+                groups.append((jnp.asarray(psk[lo:hi].reshape(k, B, 2)),
+                               jnp.asarray(pdk[lo:hi].reshape(k, B, 2)),
+                               jnp.asarray(pw[lo:hi].reshape(k, B)),
+                               jnp.asarray(mask[lo:hi].reshape(k, B))))
             else:
-                # default steady state: k flat donated dispatches with no
-                # host sync between them (donation re-checked per dispatch;
-                # after the first, the state is a fresh jit output)
-                for a in range(lo, hi, B):
+                groups.append([(jnp.asarray(psk[a:a + B]),
+                                jnp.asarray(pdk[a:a + B]),
+                                jnp.asarray(pw[a:a + B]),
+                                jnp.asarray(mask[a:a + B]))
+                               for a in range(lo, hi, B)])
+            i += k
+        return groups
+
+    def _apply(self, batch: OpBatch, req: int) -> ApplyResult:
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("graph.apply.stage", req=req):
+            groups = self._stage(batch)
+        # dispatch the super-batches ASYNCHRONOUSLY — no np.asarray() per
+        # batch
+        drops = []
+        with jax.profiler.TraceAnnotation("graph.apply.dispatch", req=req):
+            for xs in groups:
+                if isinstance(xs, tuple):
+                    # opt-in fused entry: k batches as ONE lax.scan program.
+                    # (Slower than k flat donated dispatches on XLA CPU —
+                    # the loop-carried pool scatters lose the in-place-update
+                    # optimization — but it is the single-program artifact
+                    # the dryrun lowers and the parity suite certifies.)
                     donate = self.donate_steady_state and \
                         (self.state is not self._pinned)
-                    fn = self.apply_program(donate=donate)
-                    self.state, d = fn(
-                        self.state, jnp.asarray(psk[a:a + B]),
-                        jnp.asarray(pdk[a:a + B]), jnp.asarray(pw[a:a + B]),
-                        jnp.asarray(mask[a:a + B]))
-                    drops.append(d)
-            self.stats["super_batches"] += 1
-            i += k
+                    fn = self.apply_program(donate=donate,
+                                            depth=xs[0].shape[0])
+                    self.state, d = fn(self.state, *xs)
+                    drops.append(d)             # device array — no sync here
+                else:
+                    # default steady state: k flat donated dispatches with
+                    # no host sync between them (donation re-checked per
+                    # dispatch; after the first, the state is a fresh jit
+                    # output)
+                    for x in xs:
+                        donate = self.donate_steady_state and \
+                            (self.state is not self._pinned)
+                        fn = self.apply_program(donate=donate)
+                        self.state, d = fn(self.state, *x)
+                        drops.append(d)
+                self.stats["super_batches"] += 1
         self.stats["host_stage_ms"] = round(
             self.stats["host_stage_ms"] +
             (time.perf_counter() - t0) * 1000.0, 3)
@@ -671,8 +747,9 @@ class ShardedStore:
         # chain; the defrag counter delta then attributes any rebuild
         # spike to this flush window instead of serializing every batch
         t1 = time.perf_counter()
-        dropped = int(sum(int(np.asarray(d).sum()) for d in drops))
-        dsum = int(np.asarray(self.state.pool.defrags).sum())
+        with jax.profiler.TraceAnnotation("graph.apply.wait", req=req):
+            dropped = int(sum(int(np.asarray(d).sum()) for d in drops))
+            dsum = int(np.asarray(self.state.pool.defrags).sum())
         if dsum != self._seen_defrags:            # some shard rebuilt
             now = time.perf_counter()
             self.stats["defrag_ms"] = round(
@@ -683,8 +760,8 @@ class ShardedStore:
             self.stats["defrag_sync_ms"] = round(
                 self.stats["defrag_sync_ms"] + (now - t1) * 1000.0, 3)
             self._seen_defrags = dsum
-        self.stats["device_sync_ms"] = round(
-            self.stats["device_sync_ms"] +
+        self.stats["flush_wait_ms"] = round(
+            self.stats["flush_wait_ms"] +
             (time.perf_counter() - t1) * 1000.0, 3)
         self.stats["flushes"] += 1
         self._seq += 1

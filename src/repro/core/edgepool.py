@@ -717,18 +717,19 @@ def defrag(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
     within budget); anything else — and ``defrag_impl='dense'`` — runs
     the dense entry-scatter reference. Both produce identical states.
     """
-    n_cap = vt.size.shape[0]
-    if incoming is None:
-        incoming = jnp.zeros((n_cap,), jnp.int32)
-    if spec.defrag_impl == "dense":
-        return _defrag_dense(spec, pool, vt, incoming)
-    tiers, masks, stream_ok = defrag_segments(spec, vt)
-    return jax.lax.cond(
-        stream_ok,
-        lambda args: _defrag_stream(spec, args[0], args[1], incoming,
-                                    tiers, masks),
-        lambda args: _defrag_dense(spec, args[0], args[1], incoming),
-        (pool, vt))
+    with jax.named_scope("defrag"):
+        n_cap = vt.size.shape[0]
+        if incoming is None:
+            incoming = jnp.zeros((n_cap,), jnp.int32)
+        if spec.defrag_impl == "dense":
+            return _defrag_dense(spec, pool, vt, incoming)
+        tiers, masks, stream_ok = defrag_segments(spec, vt)
+        return jax.lax.cond(
+            stream_ok,
+            lambda args: _defrag_stream(spec, args[0], args[1], incoming,
+                                        tiers, masks),
+            lambda args: _defrag_dense(spec, args[0], args[1], incoming),
+            (pool, vt))
 
 
 # --------------------------------------------------------------------------
@@ -745,73 +746,84 @@ def apply_edge_updates(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
     per-log fetch_add ordering. Returns (pool, vt, dropped) where ``dropped``
     is the number of masked ops that could not be applied (pool exhaustion);
     the distributed engine reports it per shard.
+
+    The work runs in four named scopes, one level deep, which a profiler
+    trace carries as each op's ``tf_op`` path: ``group`` (ops grouped by
+    owner, tier masks), ``compact`` (the fast-path tiers or the ``defrag``
+    rebuild), ``probe`` (pre-batch liveness of each distinct pair) and
+    ``append`` (slot claims, the slot scatter, size and counter updates).
+    With the Pallas append the window probe runs inside the ``append``
+    kernel.
     """
     B = u.shape[0]
     bs = spec.block_size
     nb = pool.dst.shape[0]
     n_cap = vt.size.shape[0]
-    valid = mask & (u >= 0) & (v >= 0)
-    ts = pool.clock + jnp.arange(B, dtype=jnp.int32)
-
-    g = _group_by(u, valid)
-    guc = jnp.clip(g["gu"], 0, n_cap - 1)
-    gsize = jnp.where(g["gvalid"], vt.size[guc], 0)
-    gcap = jnp.where(g["gvalid"], vt.cap[guc], 0)
-    need = gsize + g["gcount"]
-    govf = g["gvalid"] & (need > gcap)
-
-    # fast-path eligibility: whole current array fits the compaction buffer
-    # (a vertex whose per-batch incoming exceeds dmax defrags instead so the
-    # fast path's static extent bound always holds)
-    small_ok = govf & (gcap <= spec.dmax) & (gsize <= spec.dmax) & \
-        (g["gcount"] <= spec.dmax)
-    n_ovf = jnp.sum(govf.astype(jnp.int32))
-    n_small = jnp.sum(small_ok.astype(jnp.int32))
-    jumbo = n_ovf != n_small
-
-    # tiered fast path: first-touch vertices (no edge array at all — the
-    # bulk of any ingest stream) take the whole-batch allocation tier;
-    # in-window arrays compact at window width under the wide k_max budget;
-    # the rare big vertex pays the full dmax-width gather under the narrow
-    # k_big budget and hands the probe its liveness fold for free.
-    tier_a = small_ok & (gsize == 0) & (gcap == 0)
-    rest = small_ok & ~tier_a
     dS = min(spec.probe_width, spec.dmax)
     two_tier = dS < spec.dmax
-    tier_l = rest & (gsize > dS) if two_tier else jnp.zeros_like(rest)
-    tier_s = rest & ~tier_l
-
-    kuA = jnp.where(tier_a, g["gu"], -1)
-    kincA = jnp.where(tier_a, g["gcount"], 0)
-    base_log = spec.buf_blocks if spec.policy == "sorted" else 1
-    worstA = jnp.sum(jnp.where(tier_a, jnp.maximum(_cdiv(kincA, bs),
-                                                   base_log), 0))
-
-    def _tier(mask, k_budget):
-        kidx = jnp.nonzero(mask, size=k_budget, fill_value=B)[0]
-        kmask = kidx < B
-        kc = jnp.clip(kidx, 0, B - 1)
-        ku = jnp.where(kmask, g["gu"][kc], -1)
-        kinc = jnp.where(kmask, g["gcount"][kc], 0)
-        truncated = jnp.sum(mask.astype(jnp.int32)) > k_budget
-        # upper bound on blocks this tier may allocate:
-        worst = jnp.sum(jnp.where(kmask,
-                                  _cdiv(jnp.minimum(gsize[kc], spec.dmax),
-                                        bs) * 2 + _cdiv(kinc, bs) + 2, 0))
-        return ku, kmask, kinc, truncated, worst
-
-    kuS, kmS, kincS, truncS, worstS = _tier(tier_s, spec.k_max)
-    kuL, kmL, kincL, truncL, worstL = _tier(tier_l, spec.k_big)
-    truncated = truncS | truncL
-    pool_tight = pool.next_block + worstA + worstS + worstL > nb
-    half_garbage = pool.garbage > (nb * bs) // 2
-    do_defrag = jumbo | truncated | pool_tight | half_garbage
-
-    incoming_vec = jnp.zeros((n_cap,), jnp.int32).at[
-        jnp.where(g["gvalid"], g["gu"], n_cap)].add(g["gcount"], mode="drop")
-
     KF = spec.k_big
     Ww = _fold_words(n_cap)
+
+    with jax.named_scope("group"):
+        valid = mask & (u >= 0) & (v >= 0)
+        ts = pool.clock + jnp.arange(B, dtype=jnp.int32)
+
+        g = _group_by(u, valid)
+        guc = jnp.clip(g["gu"], 0, n_cap - 1)
+        gsize = jnp.where(g["gvalid"], vt.size[guc], 0)
+        gcap = jnp.where(g["gvalid"], vt.cap[guc], 0)
+        need = gsize + g["gcount"]
+        govf = g["gvalid"] & (need > gcap)
+
+        # fast-path eligibility: whole current array fits the compaction
+        # buffer (a vertex whose per-batch incoming exceeds dmax defrags
+        # instead so the fast path's static extent bound always holds)
+        small_ok = govf & (gcap <= spec.dmax) & (gsize <= spec.dmax) & \
+            (g["gcount"] <= spec.dmax)
+        n_ovf = jnp.sum(govf.astype(jnp.int32))
+        n_small = jnp.sum(small_ok.astype(jnp.int32))
+        jumbo = n_ovf != n_small
+
+        # tiered fast path: first-touch vertices (no edge array at all — the
+        # bulk of any ingest stream) take the whole-batch allocation tier;
+        # in-window arrays compact at window width under the wide k_max
+        # budget; the rare big vertex pays the full dmax-width gather under
+        # the narrow k_big budget and hands the probe its liveness fold for
+        # free.
+        tier_a = small_ok & (gsize == 0) & (gcap == 0)
+        rest = small_ok & ~tier_a
+        tier_l = rest & (gsize > dS) if two_tier else jnp.zeros_like(rest)
+        tier_s = rest & ~tier_l
+
+        kuA = jnp.where(tier_a, g["gu"], -1)
+        kincA = jnp.where(tier_a, g["gcount"], 0)
+        base_log = spec.buf_blocks if spec.policy == "sorted" else 1
+        worstA = jnp.sum(jnp.where(tier_a, jnp.maximum(_cdiv(kincA, bs),
+                                                       base_log), 0))
+
+        def _tier(mask, k_budget):
+            kidx = jnp.nonzero(mask, size=k_budget, fill_value=B)[0]
+            kmask = kidx < B
+            kc = jnp.clip(kidx, 0, B - 1)
+            ku = jnp.where(kmask, g["gu"][kc], -1)
+            kinc = jnp.where(kmask, g["gcount"][kc], 0)
+            truncated = jnp.sum(mask.astype(jnp.int32)) > k_budget
+            # upper bound on blocks this tier may allocate:
+            worst = jnp.sum(jnp.where(kmask,
+                                      _cdiv(jnp.minimum(gsize[kc], spec.dmax),
+                                            bs) * 2 + _cdiv(kinc, bs) + 2, 0))
+            return ku, kmask, kinc, truncated, worst
+
+        kuS, kmS, kincS, truncS, worstS = _tier(tier_s, spec.k_max)
+        kuL, kmL, kincL, truncL, worstL = _tier(tier_l, spec.k_big)
+        truncated = truncS | truncL
+        pool_tight = pool.next_block + worstA + worstS + worstL > nb
+        half_garbage = pool.garbage > (nb * bs) // 2
+        do_defrag = jumbo | truncated | pool_tight | half_garbage
+
+        incoming_vec = jnp.zeros((n_cap,), jnp.int32).at[
+            jnp.where(g["gvalid"], g["gu"], n_cap)].add(g["gcount"],
+                                                        mode="drop")
 
     def _defrag_path(args):
         pool, vt = args
@@ -836,19 +848,25 @@ def apply_edge_updates(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
                                                fold=True)
         return pool, vt, fku, fbm
 
-    pool, vt, fold_ku, fold_bitmap = jax.lax.cond(
-        do_defrag, _defrag_path, _fast_path, (pool, vt))
+    with jax.named_scope("compact"):
+        pool, vt, fold_ku, fold_bitmap = jax.lax.cond(
+            do_defrag, _defrag_path, _fast_path, (pool, vt))
 
     # ---- append every op at size + rank (log append, O(1) per op) ----
-    order = g["order"]
-    su = g["su"]
-    suc = jnp.clip(su, 0, n_cap - 1)
-    base = jnp.where(su < INT_MAX, vt.size[suc], 0)
-    slot = base + g["rank"]
-    cap_now = jnp.where(su < INT_MAX, vt.cap[suc], 0)
-    start = vt.start_block[suc]
-    op_ok = (su < INT_MAX) & (slot < cap_now) & (start >= 0)
-    dropped = jnp.sum(((su < INT_MAX) & ~op_ok).astype(jnp.int32))
+    with jax.named_scope("append"):
+        order = g["order"]
+        su = g["su"]
+        suc = jnp.clip(su, 0, n_cap - 1)
+        base = jnp.where(su < INT_MAX, vt.size[suc], 0)
+        slot = base + g["rank"]
+        cap_now = jnp.where(su < INT_MAX, vt.cap[suc], 0)
+        start = vt.start_block[suc]
+        op_ok = (su < INT_MAX) & (slot < cap_now) & (start >= 0)
+        dropped = jnp.sum(((su < INT_MAX) & ~op_ok).astype(jnp.int32))
+        sv = v[order]
+        sw_ = w[order]
+        sts = ts[order]
+        tgt_blk = jnp.where(op_ok, start + slot // bs, nb)
 
     # ---- incremental live-edge accounting (probed BEFORE the appends land):
     # a distinct (u, v) pair's post-batch liveness is decided by its LAST op;
@@ -866,106 +884,109 @@ def apply_edge_updates(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
     #      the counter dirty instead of silently drifting.
     # Drops also make the counter unreliable (an earlier op of the pair may
     # have landed): dirty, resynchronized by the next defrag / host recount.
-    op_ok_orig = jnp.zeros((B,), bool).at[order].set(op_ok)
-    pu = jnp.where(valid, u, INT_MAX)
-    pv = jnp.where(valid, v, INT_MAX)
-    porder = jnp.lexsort((ts, pv, pu))   # (u, v, ts): last-per-pair = max ts
-    u2, v2, w2 = pu[porder], pv[porder], w[porder]
-    ok2 = op_ok_orig[porder]
-    nu = jnp.concatenate([u2[1:], jnp.full((1,), -2, u2.dtype)])
-    nv = jnp.concatenate([v2[1:], jnp.full((1,), -2, v2.dtype)])
-    pair_last = ((u2 != nu) | (v2 != nv)) & (u2 < INT_MAX)
+    pallas = kops.resolve("append", spec.append_impl) == "pallas"
+    with jax.named_scope("probe"):
+        op_ok_orig = jnp.zeros((B,), bool).at[order].set(op_ok)
+        pu = jnp.where(valid, u, INT_MAX)
+        pv = jnp.where(valid, v, INT_MAX)
+        porder = jnp.lexsort((ts, pv, pu))   # (u, v, ts): last-per-pair = max ts
+        u2, v2, w2 = pu[porder], pv[porder], w[porder]
+        ok2 = op_ok_orig[porder]
+        nu = jnp.concatenate([u2[1:], jnp.full((1,), -2, u2.dtype)])
+        nv = jnp.concatenate([v2[1:], jnp.full((1,), -2, v2.dtype)])
+        pair_last = ((u2 != nu) | (v2 != nv)) & (u2 < INT_MAX)
 
-    u2c = jnp.clip(u2, 0, n_cap - 1)
-    v2c = jnp.clip(v2, 0, n_cap - 1)
-    k_of = jnp.full((n_cap + 1,), -1, jnp.int32).at[
-        jnp.where(fold_ku >= 0, fold_ku, n_cap)].set(
-            jnp.arange(KF, dtype=jnp.int32), mode="drop")[:n_cap]
-    krow = jnp.where(pair_last, k_of[u2c], -1)
-    fold_hit = krow >= 0
-    fw = fold_bitmap[jnp.clip(krow, 0, KF - 1), v2c >> 5]
-    fold_live = ((fw >> (v2c & 31).astype(jnp.uint32)) & 1) == 1
+        u2c = jnp.clip(u2, 0, n_cap - 1)
+        v2c = jnp.clip(v2, 0, n_cap - 1)
+        k_of = jnp.full((n_cap + 1,), -1, jnp.int32).at[
+            jnp.where(fold_ku >= 0, fold_ku, n_cap)].set(
+                jnp.arange(KF, dtype=jnp.int32), mode="drop")[:n_cap]
+        krow = jnp.where(pair_last, k_of[u2c], -1)
+        fold_hit = krow >= 0
+        fw = fold_bitmap[jnp.clip(krow, 0, KF - 1), v2c >> 5]
+        fold_live = ((fw >> (v2c & 31).astype(jnp.uint32)) & 1) == 1
 
-    sv = v[order]
-    sw_ = w[order]
-    sts = ts[order]
-    tgt_blk = jnp.where(op_ok, start + slot // bs, nb)
+        probe_u = jnp.where(pair_last & ~fold_hit, u2, -1)
+        p_start = jnp.where(probe_u >= 0, vt.start_block[u2c], -1)
+        p_sz = jnp.where(probe_u >= 0, vt.size[u2c], 0)
+        p_v = jnp.where(probe_u >= 0, v2, -1)
 
-    probe_u = jnp.where(pair_last & ~fold_hit, u2, -1)
-    p_start = jnp.where(probe_u >= 0, vt.start_block[u2c], -1)
-    p_sz = jnp.where(probe_u >= 0, vt.size[u2c], 0)
-    p_v = jnp.where(probe_u >= 0, v2, -1)
+        if not pallas:
+            Wp = min(spec.probe_width, spec.dmax)
+            d_e, w_e, t_e, _ = _gather_vertex_entries(spec, pool, vt,
+                                                      probe_u, Wp)
+            t_match = jnp.where(d_e == v2[:, None], t_e, 0)  # clock starts at 1
+            newest = jnp.argmax(t_match, axis=1)
+            win_was_live = (jnp.max(t_match, axis=1) > 0) & \
+                (w_e[jnp.arange(B), newest] != 0)
+            probe_blind = jnp.any((probe_u >= 0) & (p_sz > Wp))
 
-    # ---- touched-tile bound: the pool tiles any probe extent or landed
-    # slot of this batch can live in. The Pallas append only VISITS these
-    # (prefetched tile list; the grid's tail revisits the last touched
-    # tile as a no-op), and ``tiles_scanned`` records the bound on both
-    # paths — probe extents are marked as [first, last] tile RANGES via a
-    # diff/cumsum cover, so even a post-jumbo extent wider than dmax stays
-    # fully covered.
-    T = kops.append_tile_rows(nb)
-    n_tiles = nb // T
-    p_rows = _cdiv(p_sz, bs)
-    has_p = (p_start >= 0) & (p_rows > 0)
-    t_first = jnp.where(has_p, p_start // T, n_tiles)
-    t_end = jnp.where(has_p, (p_start + p_rows - 1) // T + 1, n_tiles)
-    diff = jnp.zeros((n_tiles + 1,), jnp.int32).at[t_first].add(
-        1, mode="drop").at[t_end].add(-1, mode="drop")
-    touched = jnp.cumsum(diff[:n_tiles]) > 0
-    wmark = jnp.zeros((n_tiles + 1,), bool).at[
-        jnp.where(op_ok, tgt_blk // T, n_tiles)].set(True, mode="drop")
-    touched = touched | wmark[:n_tiles]
-    n_touched = jnp.sum(touched.astype(jnp.int32))
-    t_order = jnp.nonzero(touched, size=n_tiles,
-                          fill_value=0)[0].astype(jnp.int32)
-    t_pad = t_order[jnp.clip(n_touched - 1, 0, n_tiles - 1)]
-    tiles_list = jnp.where(jnp.arange(n_tiles, dtype=jnp.int32) < n_touched,
-                           t_order, t_pad)
+    with jax.named_scope("append"):
+        # ---- touched-tile bound: the pool tiles any probe extent or landed
+        # slot of this batch can live in. The Pallas append only VISITS these
+        # (prefetched tile list; the grid's tail revisits the last touched
+        # tile as a no-op), and ``tiles_scanned`` records the bound on both
+        # paths — probe extents are marked as [first, last] tile RANGES via a
+        # diff/cumsum cover, so even a post-jumbo extent wider than dmax stays
+        # fully covered.
+        T = kops.append_tile_rows(nb)
+        n_tiles = nb // T
+        p_rows = _cdiv(p_sz, bs)
+        has_p = (p_start >= 0) & (p_rows > 0)
+        t_first = jnp.where(has_p, p_start // T, n_tiles)
+        t_end = jnp.where(has_p, (p_start + p_rows - 1) // T + 1, n_tiles)
+        diff = jnp.zeros((n_tiles + 1,), jnp.int32).at[t_first].add(
+            1, mode="drop").at[t_end].add(-1, mode="drop")
+        touched = jnp.cumsum(diff[:n_tiles]) > 0
+        wmark = jnp.zeros((n_tiles + 1,), bool).at[
+            jnp.where(op_ok, tgt_blk // T, n_tiles)].set(True, mode="drop")
+        touched = touched | wmark[:n_tiles]
+        n_touched = jnp.sum(touched.astype(jnp.int32))
+        t_order = jnp.nonzero(touched, size=n_tiles,
+                              fill_value=0)[0].astype(jnp.int32)
+        t_pad = t_order[jnp.clip(n_touched - 1, 0, n_tiles - 1)]
+        tiles_list = jnp.where(jnp.arange(n_tiles, dtype=jnp.int32) <
+                               n_touched, t_order, t_pad)
 
-    if kops.resolve("append", spec.append_impl) == "pallas":
-        # fused append: slot scatter + full-extent last-writer probe in one
-        # VMEM-resident pass per TOUCHED pool tile — exact liveness, never
-        # blind, and never a full-pool scan
-        nd, nw, nt, win_was_live = kops.append_edges(
-            pool.dst, pool.weight, pool.ts, tgt_blk, slot % bs, op_ok,
-            sv, sw_, sts, p_start, p_sz, p_v, tiles=tiles_list,
-            n_touched=n_touched, impl="pallas")
-        pool = pool._replace(dst=nd, weight=nw, ts=nt)
-        probe_blind = jnp.zeros((), bool)
-    else:
-        Wp = min(spec.probe_width, spec.dmax)
-        d_e, w_e, t_e, _ = _gather_vertex_entries(spec, pool, vt,
-                                                  probe_u, Wp)
-        t_match = jnp.where(d_e == v2[:, None], t_e, 0)  # clock starts at 1
-        newest = jnp.argmax(t_match, axis=1)
-        win_was_live = (jnp.max(t_match, axis=1) > 0) & \
-            (w_e[jnp.arange(B), newest] != 0)
-        probe_blind = jnp.any((probe_u >= 0) & (p_sz > Wp))
-        pool = _scatter_entries(pool, tgt_blk, slot % bs, op_ok, sv, sw_, sts)
+        if pallas:
+            # fused append: slot scatter + full-extent last-writer probe in
+            # one VMEM-resident pass per TOUCHED pool tile — exact liveness,
+            # never blind, and never a full-pool scan
+            nd, nw, nt, win_was_live = kops.append_edges(
+                pool.dst, pool.weight, pool.ts, tgt_blk, slot % bs, op_ok,
+                sv, sw_, sts, p_start, p_sz, p_v, tiles=tiles_list,
+                n_touched=n_touched, impl="pallas")
+            pool = pool._replace(dst=nd, weight=nw, ts=nt)
+            probe_blind = jnp.zeros((), bool)
+        else:
+            pool = _scatter_entries(pool, tgt_blk, slot % bs, op_ok, sv, sw_,
+                                    sts)
 
-    was_live = jnp.where(fold_hit, fold_live, win_was_live)
-    delta = jnp.sum(jnp.where(pair_last & ok2,
-                              (w2 != 0).astype(jnp.int32) -
-                              was_live.astype(jnp.int32), 0))
+        was_live = jnp.where(fold_hit, fold_live, win_was_live)
+        delta = jnp.sum(jnp.where(pair_last & ok2,
+                                  (w2 != 0).astype(jnp.int32) -
+                                  was_live.astype(jnp.int32), 0))
 
-    # size += written count per group
-    wrote = op_ok.astype(jnp.int32)
-    wrote_per_group = jnp.zeros((B,), jnp.int32).at[g["gid"]].add(
-        jnp.where(su < INT_MAX, wrote, 0))
-    gtgt = jnp.where(g["gvalid"], g["gu"], n_cap)
-    vt = vt._replace(size=vt.size.at[gtgt].add(
-        wrote_per_group, mode="drop"))
+        # size += written count per group
+        wrote = op_ok.astype(jnp.int32)
+        wrote_per_group = jnp.zeros((B,), jnp.int32).at[g["gid"]].add(
+            jnp.where(su < INT_MAX, wrote, 0))
+        gtgt = jnp.where(g["gvalid"], g["gu"], n_cap)
+        vt = vt._replace(size=vt.size.at[gtgt].add(
+            wrote_per_group, mode="drop"))
 
-    # updates/deletes eventually strand one stale entry each; a cheap upper
-    # estimate (¼ of writes) drives the proactive half-garbage defrag trigger
-    pool = pool._replace(clock=pool.clock + B,
-                         garbage=pool.garbage + jnp.sum(wrote) // 4,
-                         overflow=pool.overflow + jnp.where(dropped > 0, 1, 0),
-                         live_m=pool.live_m + delta,
-                         live_dirty=jnp.maximum(
-                             pool.live_dirty,
-                             ((dropped > 0) | probe_blind).astype(jnp.int32)),
-                         tiles_scanned=pool.tiles_scanned + n_touched)
+        # updates/deletes eventually strand one stale entry each; a cheap
+        # upper estimate (¼ of writes) drives the proactive half-garbage
+        # defrag trigger
+        pool = pool._replace(
+            clock=pool.clock + B,
+            garbage=pool.garbage + jnp.sum(wrote) // 4,
+            overflow=pool.overflow + jnp.where(dropped > 0, 1, 0),
+            live_m=pool.live_m + delta,
+            live_dirty=jnp.maximum(
+                pool.live_dirty,
+                ((dropped > 0) | probe_blind).astype(jnp.int32)),
+            tiles_scanned=pool.tiles_scanned + n_touched)
     return pool, vt, dropped
 
 
@@ -978,20 +999,26 @@ def get_neighbors(spec: PoolSpec, pool: EdgePool, vt: VertexTable,
     """MVCC get-neighbors for a batch of vertex offsets.
 
     Returns (dst, weight, ts, count) with rows front-packed in reverse-scan
-    order (paper's get_ngbrs = compaction-style scan, O(d))."""
+    order (paper's get_ngbrs = compaction-style scan, O(d)). Named scopes:
+    ``gather`` (the (keys x width) entry gather and the visibility filter),
+    ``compact`` (``compact_rows``)."""
     width = spec.dmax if width is None else width
     n_cap = vt.size.shape[0]
-    d, w, t, size = _gather_vertex_entries(spec, pool, vt, u, width)
-    # destination-visibility filter (paper: Del_time makes a vertex invisible)
-    dt = vt.del_time[jnp.clip(d, 0, n_cap - 1)]
-    if read_ts is None:
-        dead = (d >= 0) & (dt != 0)
-    else:
-        rts = jnp.asarray(read_ts, jnp.int32)
-        dead = (d >= 0) & (((dt > 0) & (dt <= rts)) | (dt == -1))
-    d = jnp.where(dead, -1, d)
-    rts = None if read_ts is None else jnp.asarray(read_ts, jnp.int32)
-    return kops.compact_rows(d, w, t, size, read_ts=rts, impl=spec.compact_impl)
+    with jax.named_scope("gather"):
+        d, w, t, size = _gather_vertex_entries(spec, pool, vt, u, width)
+        # destination-visibility filter (paper: Del_time makes a vertex
+        # invisible)
+        dt = vt.del_time[jnp.clip(d, 0, n_cap - 1)]
+        if read_ts is None:
+            dead = (d >= 0) & (dt != 0)
+        else:
+            rts = jnp.asarray(read_ts, jnp.int32)
+            dead = (d >= 0) & (((dt > 0) & (dt <= rts)) | (dt == -1))
+        d = jnp.where(dead, -1, d)
+    with jax.named_scope("compact"):
+        rts = None if read_ts is None else jnp.asarray(read_ts, jnp.int32)
+        return kops.compact_rows(d, w, t, size, read_ts=rts,
+                                 impl=spec.compact_impl)
 
 
 def live_edges(spec: PoolSpec, pool: EdgePool, vt: VertexTable, read_ts=None):

@@ -103,17 +103,22 @@ def step_update_edges(sspec: SortSpec, pspec: ep.PoolSpec, state: GraphState,
 
     Returns (state, dropped): ``dropped`` counts masked ops that could not be
     applied — vertex-table exhaustion (either endpoint) or pool exhaustion.
+    Every op runs under one named scope: ``vertex_index`` (SORT
+    locate-or-insert of both endpoints), then ``apply_edge_updates``'
+    ``group``, ``compact``, ``probe`` and ``append``.
     """
     B = src_keys.shape[0]
-    keys = jnp.concatenate([src_keys, dst_keys], axis=0)
-    m2 = jnp.concatenate([mask, mask])
-    st, vt, off, _ = vt_mod.ensure_vertices(sspec, state.sort, state.vt,
-                                            keys, m2)
-    u, v = off[:B], off[B:]
-    vtx_dropped = jnp.sum((mask & ((u < 0) | (v < 0))).astype(jnp.int32))
+    with jax.named_scope("vertex_index"):
+        keys = jnp.concatenate([src_keys, dst_keys], axis=0)
+        m2 = jnp.concatenate([mask, mask])
+        st, vt, off, _ = vt_mod.ensure_vertices(sspec, state.sort, state.vt,
+                                                keys, m2)
+        u, v = off[:B], off[B:]
+        vtx_dropped = jnp.sum((mask & ((u < 0) | (v < 0))).astype(jnp.int32))
     pool, vt, dropped = ep.apply_edge_updates(pspec, state.pool, vt, u, v, w,
                                               mask)
-    return GraphState(st, vt, pool), dropped + vtx_dropped
+    with jax.named_scope("append"):
+        return GraphState(st, vt, pool), dropped + vtx_dropped
 
 
 def step_update_edges_pipelined(sspec: SortSpec, pspec: ep.PoolSpec,
@@ -145,7 +150,8 @@ def step_degree_counts(sspec: SortSpec, pspec: ep.PoolSpec, state: GraphState,
                        keys, read_ts=None):
     """Live (deduplicated, tombstone-free) out-degree per query key; 0 for
     absent vertices. The owner-side answer of the distributed 1-hop query."""
-    off = sort_mod.lookup(sspec, state.sort, keys)
+    with jax.named_scope("vertex_index"):
+        off = sort_mod.lookup(sspec, state.sort, keys)
     _, _, _, cnt = ep.get_neighbors(pspec, state.pool, state.vt, off,
                                     read_ts=read_ts)
     return cnt
@@ -155,8 +161,11 @@ def step_neighbors(sspec: SortSpec, pspec: ep.PoolSpec, state: GraphState,
                    keys, width: int, read_ts=None):
     """Fused key->offset lookup + MVCC get-neighbors: one device dispatch per
     padded query batch (no host round-trip between SORT and the pool scan).
-    Returns (dst_offsets, weights, ts, counts) with rows front-packed."""
-    off = sort_mod.lookup(sspec, state.sort, keys)
+    Returns (dst_offsets, weights, ts, counts) with rows front-packed.
+    Named scopes: ``vertex_index`` (the lookup), then ``get_neighbors``'
+    ``gather`` and ``compact``."""
+    with jax.named_scope("vertex_index"):
+        off = sort_mod.lookup(sspec, state.sort, keys)
     return ep.get_neighbors(pspec, state.pool, state.vt, off,
                             read_ts=read_ts, width=width)
 
@@ -186,21 +195,25 @@ def step_snapshot(sspec: SortSpec, pspec: ep.PoolSpec, m_cap: int,
                   state: GraphState, read_ts=None):
     """Build the CSR ``GraphSnapshot`` of the live (or ``read_ts``-versioned)
     graph. Pure per-shard transition: the host wrapper jits it below and the
-    distributed engine shard_maps it per shard (``dist.graph_engine``)."""
+    distributed engine shard_maps it per shard (``dist.graph_engine``).
+    Named scopes: ``live_edges`` (the sort of the whole pool), ``csr``."""
     vt = state.vt
     n_cap = vt.size.shape[0]
-    so, sd, sw, stv, keep = ep.live_edges(pspec, state.pool, vt,
-                                          read_ts=read_ts)
-    m = jnp.sum(keep.astype(jnp.int32))
-    counts = jnp.zeros((n_cap,), jnp.int32).at[
-        jnp.where(keep, so, n_cap)].add(1, mode="drop")
-    indptr = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)])
-    # entries already sorted by (owner, dst); pack keeps to the front
-    kpos = jnp.cumsum(keep.astype(jnp.int32)) - 1
-    tgt = jnp.where(keep, kpos, m_cap)
-    dst = jnp.full((m_cap,), -1, jnp.int32).at[tgt].set(sd, mode="drop")
-    wgt = jnp.zeros((m_cap,), jnp.float32).at[tgt].set(sw, mode="drop")
-    active = vt.del_time == 0
+    with jax.named_scope("live_edges"):
+        so, sd, sw, stv, keep = ep.live_edges(pspec, state.pool, vt,
+                                              read_ts=read_ts)
+    with jax.named_scope("csr"):
+        m = jnp.sum(keep.astype(jnp.int32))
+        counts = jnp.zeros((n_cap,), jnp.int32).at[
+            jnp.where(keep, so, n_cap)].add(1, mode="drop")
+        indptr = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                  jnp.cumsum(counts)])
+        # entries already sorted by (owner, dst); pack keeps to the front
+        kpos = jnp.cumsum(keep.astype(jnp.int32)) - 1
+        tgt = jnp.where(keep, kpos, m_cap)
+        dst = jnp.full((m_cap,), -1, jnp.int32).at[tgt].set(sd, mode="drop")
+        wgt = jnp.zeros((m_cap,), jnp.float32).at[tgt].set(sw, mode="drop")
+        active = vt.del_time == 0
     return GraphSnapshot(indptr=indptr, dst=dst, weight=wgt,
                          n_rows=vt.num_rows, m=m, active=active, ids=vt.ids)
 
@@ -295,11 +308,10 @@ class RadixGraph:
         # because every spike window already records its stage/sync split
         self.defrag_host_ms: float = 0.0
         self.defrag_sync_ms: float = 0.0
-        self.defrag_batches: int = 0
         self._seen_defrags: int = 0
         # pipelined-apply accounting: a flush is one ``_apply_edge_batches``
-        # call (= one host sync point), a super-batch one device dispatch of
-        # up to ``pipeline_depth`` fused batches. ``_pinned`` names a state
+        # call (= one host wait), a super-batch one dispatch group of up to
+        # ``pipeline_depth`` device batches. ``_pinned`` names a state
         # that must not be donated (a captured epoch or MVCC version); the
         # fresh state is a jitted output with distinct leaf buffers, so the
         # very first dispatch already donates.
@@ -307,7 +319,7 @@ class RadixGraph:
         self.pipe_flushes: int = 0
         self.pipe_super_batches: int = 0
         self.pipe_stage_ms: float = 0.0   # host staging + async dispatch
-        self.pipe_sync_ms: float = 0.0    # blocked on device at the flush
+        self.pipe_wait_ms: float = 0.0    # host blocked on the flush's result
 
     # ---- batching helpers ----
     def _pad(self, arr, fill, dtype):
@@ -361,23 +373,9 @@ class RadixGraph:
                                           self.state, keys)))
         return np.concatenate(out)[:n] if out else np.zeros(0, np.int32)
 
-    def _edge_batches(self, src, dst, w):
-        src = np.asarray(src, np.uint64)
-        dst = np.asarray(dst, np.uint64)
-        w = np.asarray(w, np.float32)
-        if self.undirected:
-            src, dst, w = interleave_undirected(src, dst, w)
-        ps, mask = self._pad(src, 0, np.uint64)
-        pd, _ = self._pad(dst, 0, np.uint64)
-        pw, _ = self._pad(w, 0, np.float32)
-        B = self.batch
-        for i in range(0, ps.shape[0], B):
-            yield (pack_keys(ps[i:i + B], self.key_bits),
-                   pack_keys(pd[i:i + B], self.key_bits),
-                   jnp.asarray(pw[i:i + B]), jnp.asarray(mask[i:i + B]))
-
-    def _edge_super_batches(self, src, dst, w):
-        """Super-batches of depth <= ``pipeline_depth``: groups of k flat
+    def _edge_super_batches(self, src, dst, w) -> list:
+        """The staged flush: interleave, pad, pack and put on the device.
+        Super-batches of depth <= ``pipeline_depth``: groups of k flat
         (B, ...) batch tuples by default, or ONE stacked (k, B, ...) tuple
         when ``fuse_scan`` is set (the single-program ``lax.scan`` entry).
         The ragged tail ships at its true depth k' < K (jit retraces per
@@ -396,20 +394,23 @@ class RadixGraph:
         K = max(1, int(self.pipeline_depth))
         sk = pack_keys(ps, self.key_bits)       # one packing pass, reshaped
         dk = pack_keys(pd, self.key_bits)       # into (k, B, 2) slices below
+        out = []
         i = 0
         while i < NB:
             k = min(K, NB - i)
             lo, hi = i * B, (i + k) * B
             if k > 1 and self.fuse_scan:
-                yield k, (jnp.reshape(sk[lo:hi], (k, B, 2)),
-                          jnp.reshape(dk[lo:hi], (k, B, 2)),
-                          jnp.asarray(pw[lo:hi].reshape(k, B)),
-                          jnp.asarray(mask[lo:hi].reshape(k, B)))
+                out.append((jnp.reshape(sk[lo:hi], (k, B, 2)),
+                            jnp.reshape(dk[lo:hi], (k, B, 2)),
+                            jnp.asarray(pw[lo:hi].reshape(k, B)),
+                            jnp.asarray(mask[lo:hi].reshape(k, B))))
             else:
-                yield k, [(sk[a:a + B], dk[a:a + B], jnp.asarray(pw[a:a + B]),
-                           jnp.asarray(mask[a:a + B]))
-                          for a in range(lo, hi, B)]
+                out.append([(sk[a:a + B], dk[a:a + B],
+                             jnp.asarray(pw[a:a + B]),
+                             jnp.asarray(mask[a:a + B]))
+                            for a in range(lo, hi, B)])
             i += k
+        return out
 
     def _note_spike(self, t0: float, t1: Optional[float] = None):
         """Attribute the finished op's wall time to the spike accounting
@@ -425,7 +426,6 @@ class RadixGraph:
                 * 1000.0
             if t1 is not None:
                 self.defrag_sync_ms += (now - t1) * 1000.0
-            self.defrag_batches += d - self._seen_defrags
             self._seen_defrags = d
 
     def pin_live_state(self):
@@ -436,38 +436,50 @@ class RadixGraph:
         the retained buffers."""
         self._pinned = self.state
 
-    def _apply_edge_batches(self, src, dst, w):
+    def _apply_edge_batches(self, src, dst, w, req: int = 0):
+        """One flush, in three host spans that carry the request number
+        ``req``: ``graph.apply.stage`` (``_edge_super_batches``),
+        ``graph.apply.dispatch`` (the asynchronous step calls) and
+        ``graph.apply.wait`` (the drop-counter fetch, the flush's one
+        sync)."""
         self._invalidate()
         t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("graph.apply.stage", req=req):
+            groups = self._edge_super_batches(src, dst, w)
         drops = []
-        for k, xs in self._edge_super_batches(src, dst, w):
-            if isinstance(xs, list):
-                # default steady state: k flat donated dispatches with NO
-                # host sync between them. Measured faster than the fused
-                # lax.scan program on XLA CPU, where the loop-carried pool
-                # scatters lose the in-place-update optimization the flat
-                # program gets (~4x per batch at benchmark capacities).
-                for x in xs:
+        with jax.profiler.TraceAnnotation("graph.apply.dispatch", req=req):
+            for xs in groups:
+                if isinstance(xs, list):
+                    # default steady state: k flat donated dispatches with
+                    # NO host sync between them. Measured faster than the
+                    # fused lax.scan program on XLA CPU, where the
+                    # loop-carried pool scatters lose the in-place-update
+                    # optimization the flat program gets (~4x per batch at
+                    # benchmark capacities).
+                    for x in xs:
+                        donate = self.donate_apply and \
+                            (self.state is not self._pinned)
+                        fn = _update_edges_donate if donate else _update_edges
+                        self.state, d = fn(self.sort_spec, self.pool_spec,
+                                           self.state, *x)
+                        drops.append(d)        # device scalar — no sync here
+                else:
                     donate = self.donate_apply and \
                         (self.state is not self._pinned)
-                    fn = _update_edges_donate if donate else _update_edges
+                    fn = _update_edges_pipe_donate if donate \
+                        else _update_edges_pipe
                     self.state, d = fn(self.sort_spec, self.pool_spec,
-                                       self.state, *x)
-                    drops.append(d)            # device scalar — no sync here
-            else:
-                donate = self.donate_apply and (self.state is not self._pinned)
-                fn = _update_edges_pipe_donate if donate else _update_edges_pipe
-                self.state, d = fn(self.sort_spec, self.pool_spec,
-                                   self.state, *xs)
-                drops.append(d)
-            self.pipe_super_batches += 1
+                                       self.state, *xs)
+                    drops.append(d)
+                self.pipe_super_batches += 1
         self.pipe_stage_ms += (time.perf_counter() - t0) * 1000.0
         t1 = time.perf_counter()
         # ONE host sync per flush: fetching the drop counters forces the
         # whole dispatched chain; the defrag watermark delta then attributes
         # any rebuild spike to this flush window
-        self.dropped_ops += sum(int(d) for d in drops)
-        self.pipe_sync_ms += (time.perf_counter() - t1) * 1000.0
+        with jax.profiler.TraceAnnotation("graph.apply.wait", req=req):
+            self.dropped_ops += sum(int(d) for d in drops)
+        self.pipe_wait_ms += (time.perf_counter() - t1) * 1000.0
         self.pipe_flushes += 1
         self._note_spike(t0, t1)
 
@@ -483,10 +495,12 @@ class RadixGraph:
         w = np.zeros(len(np.asarray(src)), np.float32)  # NULL tombstones
         self._apply_edge_batches(src, dst, w)
 
-    def apply_ops(self, src, dst, weight):
+    def apply_ops(self, src, dst, weight, req: int = 0):
         """Order-preserving mixed stream: weight==0 deletes, else insert/update
-        (the paper's mixed-updates workload, Fig. 9)."""
-        self._apply_edge_batches(src, dst, np.asarray(weight, np.float32))
+        (the paper's mixed-updates workload, Fig. 9). ``req`` labels the
+        flush's trace spans."""
+        self._apply_edge_batches(src, dst, np.asarray(weight, np.float32),
+                                 req)
 
     def neighbors(self, ids, width=None, read_ts=None, as_ids=True):
         """Get-neighbors for a batch of vertex IDs (paper: O(d) per vertex).
