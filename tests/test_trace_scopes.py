@@ -177,3 +177,59 @@ def test_sharded_store_renames_its_wait():
     assert "device_sync_ms" not in store.stats
     store.apply(OpBatch.edges(ids[:20], ids[20:], np.ones(20, np.float32)))
     assert store.stats["flushes"] == 1 and store.stats["flush_wait_ms"] >= 0
+
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(([^)]*)\)")
+_COMP = re.compile(r"^(?:ENTRY )?%(\S+) \(")
+
+
+def _gathers_fed_by_a_sort(hlo):
+    """Names of the gathers of a compiled HLO module whose indices derive
+    from a sort's output, traced through fused computations."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        head = _COMP.match(line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            if line.startswith("ENTRY"):
+                entry = head.group(1)
+            continue
+        ins = _INSTR.match(line)
+        if ins and cur is not None:
+            calls = re.search(r"calls=%(\S+?)[,\s]", line + " ")
+            cur.append((ins.group(1), ins.group(3),
+                        re.findall(r"%([\w.-]+)", ins.group(4)),
+                        ins.group(4), calls and calls.group(1)))
+    found = []
+
+    def walk(name, tainted_params):
+        tainted = set()
+        for iname, op, operands, args, calls in comps[name]:
+            if op == "parameter":
+                if int(args) in tainted_params:
+                    tainted.add(iname)
+                continue
+            hit = [o in tainted for o in operands]
+            if op == "gather" and hit[1]:
+                found.append(iname)
+            if calls:
+                walk(calls, {i for i, h in enumerate(hit) if h})
+            if op == "sort" or any(hit):
+                tainted.add(iname)
+
+    walk(entry, set())
+    return found
+
+
+def test_snapshot_sort_carries_the_weight_and_no_gather_follows_it(graph):
+    """The snapshot sorts (owner, dst, ts) with the weight as a carried
+    operand, so no per-entry gather puts values back in sorted order."""
+    fn, args, _ = _programs(graph)["step_snapshot"]
+    hlo = fn.lower(*args).compile().as_text()
+    sorts = [line for line in hlo.splitlines() if _OP.match(line)
+             and _OP.match(line).group(1) == "sort" and "/live_edges/" in line]
+    assert len(sorts) == 1, sorts
+    operands = re.match(r"^\s*(?:ROOT )?%\S+ = \(([^)]*)\)", sorts[0])
+    assert operands and "f32[" in operands.group(1), sorts[0][:200]
+    assert _gathers_fed_by_a_sort(hlo) == []
